@@ -4,9 +4,8 @@ Compares three implementations of batched membership/intersection-count
 over sorted padded neighbor lists:
   binary-search : the portable executor path (vectorized per-segment
                   binary search over flat CSR),
-  pallas        : blocked broadcast-compare kernel (interpret mode on
-                  CPU — correctness + lowering; the HLO it emits is the
-                  TPU path),
+  pallas        : blocked broadcast-compare kernel (Pallas interpreter
+                  on CPU — correctness only; Mosaic on a TPU),
   jnp-ref       : the pure-jnp oracle (ref.py).
 
 On CPU only relative correctness + rough timing are meaningful; the
@@ -23,6 +22,11 @@ import numpy as np
 from repro.kernels import ops, ref
 
 from ._util import Row, emit
+
+# The kernels lower to Mosaic on a TPU and run in the Pallas
+# interpreter on any other backend; a CPU row is a correctness check,
+# not a device time.
+INTERPRET = jax.default_backend() != "tpu"
 
 SHAPES_QUICK = [(256, 128, 128), (512, 128, 256)]
 SHAPES_FULL = [(256, 128, 128), (512, 128, 256), (1024, 256, 512),
@@ -55,14 +59,16 @@ def run(full: bool = False) -> list[Row]:
     for (B, D, L) in (SHAPES_FULL if full else SHAPES_QUICK):
         cand, nbr = _data(B, D, L)
         out_ref = jref(cand, nbr)
-        out_pl = ops.sorted_membership(cand, nbr)
+        out_pl = ops.sorted_membership(cand, nbr, interpret=INTERPRET)
         assert bool(jnp.all(out_ref == out_pl)), (B, D, L)
 
-        t_pl = _time(lambda: ops.sorted_membership(cand, nbr))
+        t_pl = _time(lambda: ops.sorted_membership(cand, nbr,
+                                                   interpret=INTERPRET))
         t_ref = _time(lambda: jref(cand, nbr))
-        cnt_pl = ops.intersect_count(cand, nbr)
+        cnt_pl = ops.intersect_count(cand, nbr, interpret=INTERPRET)
         assert bool(jnp.all(cnt_pl == out_ref.sum(axis=1)))
-        t_cnt = _time(lambda: ops.intersect_count(cand, nbr))
+        t_cnt = _time(lambda: ops.intersect_count(cand, nbr,
+                                                  interpret=INTERPRET))
 
         compares = B * D * L
         rows.append(Row("kernel", {"B": B, "D": D, "L": L,
@@ -137,26 +143,27 @@ def run_level(full: bool = False) -> list[Row]:
                 window = flat[starts[p][:, None]
                               + jnp.arange(L, dtype=jnp.int32)[None, :]]
                 mask &= ops.sorted_membership(cand, window,
-                                              nbr_len=lens[p])
+                                              nbr_len=lens[p],
+                                              interpret=INTERPRET)
             for e, d in enumerate(dirs):
                 ev = extra[:, e][:, None]
                 mask &= (cand > ev) if d > 0 else (cand != ev)
             return mask
 
         fused = lambda: ops.level_expand(cand, flat, starts, lens, extra,
-                                         dirs=dirs, window=L)
+                                         dirs=dirs, interpret=INTERPRET)
         out_old = per_pred(cand, flat, starts, lens, extra)
         out_new = fused()
         assert bool(jnp.all(out_old == out_new)), (B, D, P, L)
         cnt = ops.level_expand(cand, flat, starts, lens, extra,
-                               dirs=dirs, window=L, count=True)
+                               dirs=dirs, count=True, interpret=INTERPRET)
         assert bool(jnp.all(cnt == out_old.sum(axis=1))), (B, D, P, L)
 
         t_old = _time(lambda: per_pred(cand, flat, starts, lens, extra))
         t_new = _time(fused)
         t_cnt = _time(lambda: ops.level_expand(cand, flat, starts, lens,
-                                               extra, dirs=dirs, window=L,
-                                               count=True))
+                                               extra, dirs=dirs, count=True,
+                                               interpret=INTERPRET))
         compares = B * D * L * P
         # operand HBM peaks (MB): what must be live at once to feed the
         # kernel, beyond the resident CSR itself

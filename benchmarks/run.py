@@ -53,7 +53,9 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default="",
                     help="comma-separated subset of " + ",".join(BENCHES))
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     names = [n.strip() for n in args.only.split(",") if n.strip()] or \
         list(BENCHES)
     failures = []

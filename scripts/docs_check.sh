@@ -6,7 +6,8 @@
 #   3. every path-looking token in README.md shell snippets names a
 #      real file, and every `python -m pkg.mod` names a real module;
 #   4. no stale references: every `repro.x.y` dotted module and every
-#      `src/repro/...` path mentioned anywhere in the docs still exists;
+#      `src/repro/...` path mentioned in the docs (all but the CHANGES.md
+#      history) still exists;
 #   5. no orphan packages: every directory package under src/repro (any
 #      depth) is mentioned in README.md or DESIGN.md.
 set -euo pipefail
@@ -69,7 +70,10 @@ for block in snippets:
                 fail.append(f"README snippet names missing file: {tok}")
 
 # --- 4. stale module / path references ------------------------------------
-doc_texts = {md: open(md).read() for md in md_files}
+# CHANGES.md is the per-PR history: its past entries name code that a
+# later PR removed, and they stay as written.
+doc_texts = {md: open(md).read() for md in md_files
+             if os.path.basename(md) != "CHANGES.md"}
 for md, text in doc_texts.items():
     for path in set(re.findall(r"src/repro[\w/.-]*", text)):
         path = path.rstrip(".")           # sentence-final period

@@ -2,16 +2,18 @@
 
 The fused level-expansion kernel (kernels/intersect.py) states its
 safety contract only in docstrings: int32 operands, block-multiple
-shapes, `block_l ≤ MAX_BLOCK_L` so `flat_gather_pad()` sentinels cover
-the furthest in-grid DMA, rows inside the unpadded flat array, and CSR
-offsets that fit int32.  Violations today surface at trace time or —
-for the DMA window and offset-overflow cases — as wrong reads on
-device.  This pass proves the contract abstractly for a given
-`GraphCSR` shape and `ExecutorConfig`, mirroring the exact call shapes
-the executor generates (one spec per degree bucket, enumeration and
-IEP-tail variants), and abstractly evaluates the real `ops.level_expand`
-wrapper via `jax.eval_shape` + jaxpr inspection so dtype/shape drift in
-the wrapper itself is caught without compiling or running anything.
+shapes, a power-of-two gather tile `block_l` so that the aligned tiles
+covering every CSR row are rows of the [F/block_l, block_l] view of the
+flat array, rows inside the unpadded flat array, scalar-prefetched
+offsets that fit in SMEM, and CSR offsets that fit int32.  Violations
+surface at trace time, as a Mosaic refusal on the chip, or — for the
+offset-overflow case — as wrong reads on device.  This pass proves the
+contract abstractly for a given `GraphCSR` shape and `ExecutorConfig`,
+mirroring the exact call shapes the executor generates (one spec per
+degree bucket, enumeration and IEP-tail variants), and abstractly
+evaluates the real `ops.level_expand` wrapper via `jax.eval_shape` +
+jaxpr inspection so dtype/shape drift in the wrapper itself is caught
+without compiling or running anything.
 """
 from __future__ import annotations
 
@@ -35,75 +37,82 @@ class LevelExpandSpec:
     B/D are the pre-padding candidate-matrix shape (the wrapper pads to
     block multiples); `flat_len` is the UNPADDED CSR indices length
     (row extents must end inside it); `padded` says the caller ships a
-    `flat_gather_pad()`-sentinel tail (`flat_padded=True` — the
-    resident-graph path through `core.executor.device_graph`).
+    `flat_gather_pad()`-sentinel tail that makes whole gather tiles
+    (the resident-graph path through `core.executor.device_graph`).
     """
 
     B: int                    # frontier rows
     D: int                    # candidate columns (window or window+depth)
     P: int                    # predecessor count
     E: int = 0                # extra (restriction/injectivity) columns
-    window: int = 0           # static row-length bound (graph max degree)
-    flat_len: int = 0         # unpadded flat CSR length (2m)
+    flat_len: int = 0         # unpadded flat CSR length (2m + pad)
     count: bool = False
     neg_from: int | None = None
     padded: bool = True
     block_b: int = 8
-    block_d: int = 128
     block_l: int = 128
     label: str = "level_expand"
 
 
 def check_spec(spec: LevelExpandSpec) -> list[Finding]:
     """Contract proofs that need no tracing at all."""
-    from ..kernels.ops import MAX_BLOCK_L, flat_gather_pad
+    from ..kernels.intersect import SMEM_PREFETCH_BYTES
+    from ..kernels.ops import GATHER_TILE
 
     loc = spec.label
     out: list[Finding] = []
-    if spec.block_l > MAX_BLOCK_L:
+    bl = spec.block_l
+    if bl <= 0 or bl & (bl - 1):
         out.append(_err(
             "kernel-dma-window", loc,
-            f"block_l={spec.block_l} > MAX_BLOCK_L={MAX_BLOCK_L}: the "
-            f"furthest in-grid DMA reads up to flat_len + block_l - 1, "
-            f"past the {flat_gather_pad()}-sentinel pad — out-of-bounds "
-            f"HBM reads on device"))
-    if spec.padded and spec.window > 0 and spec.flat_len == 0:
+            f"block_l={bl} is not a power of two: the kernel finds a "
+            f"row's first gather tile by shifting its CSR offset"))
+    elif spec.padded and GATHER_TILE % bl:
+        out.append(Finding(
+            WARNING, "kernel-dma-window", loc,
+            f"block_l={bl} does not divide the resident pad unit "
+            f"{GATHER_TILE}: every call re-pads (copies) the flat array "
+            f"to whole {bl}-wide tiles"))
+    if spec.padded and spec.flat_len == 0:
         out.append(Finding(
             WARNING, "kernel-dma-window", loc,
             "flat_padded=True with an unknown flat length: cannot prove "
             "the row-extent invariant starts + lens <= flat_len"))
     for name, val, mult in (("block_b", spec.block_b, 8),
-                            ("block_d", spec.block_d, 128),
-                            ("block_l", spec.block_l, 128)):
+                            ("block_l", bl, 128)):
         if val <= 0 or val % mult:
             out.append(_err(
                 "kernel-block-shape", loc,
                 f"{name}={val} is not a positive multiple of {mult} "
                 f"(TPU lane/sublane tiling)"))
-    if spec.window <= 0:
-        out.append(_err(
-            "kernel-window", loc,
-            f"window={spec.window}: the grid would walk zero neighbor "
-            f"blocks and every membership test would be vacuously false"))
     if spec.neg_from is not None and not (0 <= spec.neg_from <= spec.D):
         out.append(_err(
             "kernel-window", loc,
             f"neg_from={spec.neg_from} outside candidate columns "
             f"0..{spec.D}: the signed IEP popcount would mis-weight real "
             f"candidates"))
+    # SMEM: one call prefetches [P, rows] offsets and lengths; the
+    # wrapper splits a frontier into block_b-multiple row groups, so the
+    # smallest group must fit
+    if 2 * 4 * spec.P * spec.block_b > SMEM_PREFETCH_BYTES:
+        out.append(_err(
+            "kernel-smem", loc,
+            f"P={spec.P} predecessors: even {spec.block_b} rows of "
+            f"offsets + lengths exceed the {SMEM_PREFETCH_BYTES}-byte "
+            f"scalar-prefetch budget"))
 
-    # int32 offset overflow: the kernel computes starts + li*block_l in
-    # int32 SMEM; the largest offset it can form is
-    # flat_len + round_up(window, block_l).
-    if spec.window > 0 and spec.block_l > 0:
-        nl = max(-(-spec.window // spec.block_l), 1)
-        reach = spec.flat_len + nl * spec.block_l
+    # int32 reach: the kernel forms tile indices and absolute positions
+    # in int32 SMEM scalars; the largest is the end of the last tile,
+    # round_up(flat_len, block_l).
+    if bl > 0:
+        reach = -(-spec.flat_len // bl) * bl
         if reach > INT32_MAX:
             out.append(_err(
                 "kernel-int32-offset", loc,
-                f"max DMA offset {reach} (flat_len={spec.flat_len} + "
-                f"{nl}x{spec.block_l}) overflows int32: CSR offsets wrap "
-                f"and the kernel reads the wrong neighborhoods"))
+                f"last gather tile ends at {reach} (flat_len="
+                f"{spec.flat_len}, tiles of {bl}), past int32: CSR "
+                f"offsets wrap and the kernel reads the wrong "
+                f"neighborhoods"))
     return out
 
 
@@ -119,7 +128,7 @@ def abstract_eval_spec(spec: LevelExpandSpec) -> list[Finding]:
 
     loc = spec.label
     out: list[Finding] = []
-    pad = ops.flat_gather_pad() if spec.padded else 0
+    pad = ops.flat_gather_pad(spec.flat_len) if spec.padded else 0
     cand = jax.ShapeDtypeStruct((spec.B, spec.D), jnp.int32)
     flat = jax.ShapeDtypeStruct((spec.flat_len + pad,), jnp.int32)
     starts = jax.ShapeDtypeStruct((spec.P, spec.B), jnp.int32)
@@ -133,9 +142,7 @@ def abstract_eval_spec(spec: LevelExpandSpec) -> list[Finding]:
         return ops.level_expand(
             cand, flat, starts, lens, extra, valid,
             dirs=dirs, count=spec.count, neg_from=spec.neg_from,
-            window=spec.window, flat_padded=spec.padded,
-            block_b=spec.block_b, block_d=spec.block_d,
-            block_l=spec.block_l, interpret=True,
+            block_b=spec.block_b, block_l=spec.block_l, interpret=True,
         )
 
     try:
@@ -156,7 +163,8 @@ def abstract_eval_spec(spec: LevelExpandSpec) -> list[Finding]:
 
     # jaxpr inspection: a pallas_call must be on the traced path and its
     # integer array operands must all be int32 (dtype drift to int64 —
-    # e.g. under x64 — doubles DMA widths and breaks the SMEM prefetch).
+    # e.g. under x64 — doubles DMA widths and breaks the SMEM prefetch;
+    # the wrapper traces the kernel with x64 off).
     try:
         jaxpr = jax.make_jaxpr(call)(cand, flat, starts, lens, extra, valid)
     except Exception:               # eval_shape above already vetted it
@@ -212,7 +220,7 @@ def executor_specs(n: int, m: int, max_degree: int, cfg=None,
 
     cfg = cfg or ExecutorConfig()
     W = max(int(max_degree), 1)
-    flat_len = 2 * int(m)
+    flat_len = 2 * int(m) + W          # CSR build's max-degree pad
     buckets = cfg.degree_buckets
     if buckets is not None:
         buckets = tuple((min(int(w), W), float(f)) for (w, f) in buckets)
@@ -223,7 +231,7 @@ def executor_specs(n: int, m: int, max_degree: int, cfg=None,
     specs = []
     for bi, (width, frac) in enumerate(buckets):
         cap = max(int(cfg.capacity * frac), 8)
-        base = dict(P=2, window=W, flat_len=flat_len, padded=True)
+        base = dict(P=2, flat_len=flat_len, padded=True)
         specs.append(LevelExpandSpec(
             B=cap, D=width, E=2, count=False,
             label=f"{label}/bucket{bi}[w={width}]/mask", **base))
@@ -258,12 +266,14 @@ def check_graph_contract(graph_or_shape, cfg=None, *,
     out: list[Finding] = []
     from ..kernels.ops import flat_gather_pad
 
-    if 2 * m + flat_gather_pad() > INT32_MAX:
+    flat_len = 2 * m + W
+    padded = flat_len + flat_gather_pad(flat_len)
+    if padded > INT32_MAX:
         out.append(_err(
             "kernel-int32-offset", label,
-            f"padded flat CSR length {2 * m + flat_gather_pad()} "
-            f"overflows int32 indexing; the graph needs int64 offsets "
-            f"the kernel does not implement"))
+            f"padded flat CSR length {padded} overflows int32 indexing; "
+            f"the graph needs int64 offsets the kernel does not "
+            f"implement"))
     if n > INT32_MAX:
         out.append(_err(
             "kernel-int32-offset", label,

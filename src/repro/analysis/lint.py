@@ -22,15 +22,6 @@ The rule classes over `src/repro`:
                           `repro.obs` (`timer()`, `Timer`, tracer
                           spans) is the one clock; obs/ itself is the
                           sanctioned home of the raw calls.
-  compat-only-drift       JAX APIs that moved between releases
-                          (shard_map, enable_x64, export,
-                          sharding.set_mesh/get_abstract_mesh) are
-                          shimmed once in compat.py; every other module
-                          must import the shim, never either home
-                          directly — old OR new, since using the new
-                          home directly silently breaks the pin.
-                          `jax.experimental.pallas` is not drifted and
-                          stays allowed.
   no-tracer-concretize    inside jit-decorated functions and Pallas
                           kernel bodies (`*_body` / `*_kernel`),
                           `.item()`, `int(x)`, `float(x)` on traced
@@ -74,26 +65,6 @@ import ast
 from pathlib import Path
 
 from .findings import ERROR, Finding
-
-# dotted names whose ONLY sanctioned home is compat.py (old + new homes)
-_DRIFTED_ATTRS = {
-    "jax.experimental.shard_map",
-    "jax.experimental.enable_x64",
-    "jax.experimental.export",
-    "jax.shard_map",
-    "jax.enable_x64",
-    "jax.export",
-    "jax.sharding.set_mesh",
-    "jax.sharding.get_abstract_mesh",
-}
-# `from <module> import <name>` forms of the same APIs
-_DRIFTED_FROM = {
-    "jax.experimental": {"shard_map", "enable_x64", "export"},
-    "jax.experimental.shard_map": None,      # None = any name
-    "jax.experimental.export": None,
-    "jax": {"shard_map", "enable_x64", "export"},
-    "jax.sharding": {"set_mesh", "get_abstract_mesh"},
-}
 
 _NONDETERMINISTIC_MODULES = {"random", "secrets", "uuid"}
 _NONDETERMINISTIC_ATTRS = {
@@ -282,7 +253,6 @@ def lint_source(src: str, rel: str) -> list[Finding]:
 
     posix = rel.replace("\\", "/")
     is_scheduler = posix.endswith("serve/scheduler.py")
-    is_compat = posix.endswith("repro/compat.py")
     is_timed = _in_timed_scope(rel)
     out: list[Finding] = []
     for suffix, surfaces in _LABEL_SURFACES.items():
@@ -328,15 +298,6 @@ def lint_source(src: str, rel: str) -> list[Finding]:
                             f"the serve/query path — use repro.obs "
                             f"(timer()/Timer or a tracer span) so the "
                             f"measurement reaches the metrics registry"))
-            if not is_compat and mod in _DRIFTED_FROM:
-                allowed = _DRIFTED_FROM[mod]
-                names = [a.name for a in node.names
-                         if allowed is None or a.name in allowed]
-                for name in names:
-                    out.append(_err(
-                        "compat-only-drift", loc,
-                        f"from {mod} import {name}: drifted JAX API — "
-                        f"import it from repro.compat instead"))
 
         elif isinstance(node, ast.Attribute):
             name = _dotted(node)
@@ -346,10 +307,6 @@ def lint_source(src: str, rel: str) -> list[Finding]:
                 out.append(_err(
                     "scheduler-no-jax", loc,
                     f"{name}: the scheduler must not touch JAX"))
-            if not is_compat and name in _DRIFTED_ATTRS:
-                out.append(_err(
-                    "compat-only-drift", loc,
-                    f"{name}: drifted JAX API — go through repro.compat"))
             if is_scheduler and name in _NONDETERMINISTIC_ATTRS:
                 out.append(_err(
                     "scheduler-determinism", loc,

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import enable_x64, shard_map
 from ..graph.csr import GraphCSR
 from ..obs import get_tracer
 from .pattern import Pattern, clique
@@ -84,9 +83,10 @@ class ExecutorConfig:
     capacity: int = 1 << 15          # frontier rows per level
     dynamic_base: bool = True        # per-row min-degree base predecessor
     # Fused Pallas level-expansion kernel (the TPU hot path).  None =
-    # auto: True on TPU backends, False elsewhere — interpret-mode
-    # Pallas is bit-exact but slow, so CPU/CI defaults to the portable
-    # binary-search path; parity tests force True explicitly.
+    # auto: True on TPU backends, False elsewhere (the portable
+    # binary-search path).  True on a backend without Mosaic runs the
+    # Pallas interpreter — bit-exact but slow, for parity tests — and
+    # says so in the fingerprint (`pallas=interpret`).
     use_pallas: bool | None = None
     # Degree-bucketed expansion (§Perf, graphpi cell): ((width, frac), ...)
     # ascending widths; rows whose base degree fits a narrower window are
@@ -102,6 +102,14 @@ class ExecutorConfig:
             return jax.default_backend() == "tpu"
         return self.use_pallas
 
+    def pallas_mode(self) -> str:
+        """How the level expansion runs: "0" portable, "1" the Mosaic
+        kernel compiled for the TPU, "interpret" the Pallas interpreter
+        (the fused path on any other backend)."""
+        if not self.resolve_use_pallas():
+            return "0"
+        return "1" if jax.default_backend() == "tpu" else "interpret"
+
     def fingerprint(self) -> str:
         """Stable string of the facets baked into a jitted count program
         (capacity, base selection, RESOLVED pallas path, bucket layout).
@@ -110,7 +118,7 @@ class ExecutorConfig:
         buckets = "none" if self.degree_buckets is None else ";".join(
             f"{int(w)}:{float(f):.6g}" for w, f in self.degree_buckets)
         return (f"cap={self.capacity},dyn={int(self.dynamic_base)},"
-                f"pallas={int(self.resolve_use_pallas())},"
+                f"pallas={self.pallas_mode()},"
                 f"buckets={buckets}")
 
 
@@ -207,6 +215,7 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
     depth = plan.depth
     C = cfg.capacity
     use_pallas = cfg.resolve_use_pallas()
+    interpret = cfg.pallas_mode() == "interpret"
     # Static per-position label requirements (None = wildcard / unlabeled).
     vlabels = plan.vlabels or (None,) * n
 
@@ -301,7 +310,7 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
                 else None,
                 mask,
                 dirs=tuple(d for _, d in extras), count=want_counts,
-                window=W, flat_padded=True,
+                interpret=interpret,
             )
             return res if want_counts else (cand, res)
         if len(preds) > 1:
@@ -372,31 +381,52 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
                 rowmask &= db <= width
             sel_idx, sub_valid, sub_total = select_rows(rowmask, cap)
             needed = jnp.maximum(needed, scaled_need(sub_total, cap))
-            sub_emb = jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i]
-            sub_base = jnp.take(base_all, sel_idx, mode="clip")
-            if last_enum:
+
+            def bucket_count(sel_idx=sel_idx, sub_valid=sub_valid,
+                             width=width):
                 cnts = expand_core(
-                    sub_emb, sub_base, sub_valid, preds, extras,
-                    indptr, degrees, flat, width, want_counts=True,
+                    jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i],
+                    jnp.take(base_all, sel_idx, mode="clip"), sub_valid,
+                    preds, extras, indptr, degrees, flat, width,
+                    want_counts=True, labs=labs, label=label,
+                )
+                return jnp.sum(cnts, dtype=jnp.int64)
+
+            def bucket_expand(carry, sel_idx=sel_idx, sub_valid=sub_valid,
+                              width=width, cap=cap):
+                parent, newcol, offset = carry
+                cand, mask = expand_core(
+                    jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i],
+                    jnp.take(base_all, sel_idx, mode="clip"), sub_valid,
+                    preds, extras, indptr, degrees, flat, width,
                     labs=labs, label=label,
                 )
-                total_cnt += jnp.sum(cnts, dtype=jnp.int64)
-                continue
-            cand, mask = expand_core(
-                sub_emb, sub_base, sub_valid, preds, extras,
-                indptr, degrees, flat, width,
-                labs=labs, label=label,
-            )
-            # stream-compact surviving (row, cand) pairs behind `offset`
-            flat_mask = mask.reshape(-1)
-            pos = jnp.cumsum(flat_mask) - 1
-            bucket_total = (pos[-1] + 1).astype(jnp.int32)
-            out_idx = jnp.where(flat_mask, jnp.minimum(offset + pos, C), C)
-            rows_local = jnp.arange(cap * width, dtype=jnp.int32) // width
-            parent = parent.at[out_idx].set(
-                jnp.take(sel_idx, rows_local), mode="drop")
-            newcol = newcol.at[out_idx].set(cand.reshape(-1), mode="drop")
-            offset = offset + bucket_total
+                # stream-compact surviving (row, cand) pairs behind `offset`
+                flat_mask = mask.reshape(-1)
+                pos = jnp.cumsum(flat_mask) - 1
+                bucket_total = (pos[-1] + 1).astype(jnp.int32)
+                out_idx = jnp.where(flat_mask,
+                                    jnp.minimum(offset + pos, C), C)
+                rows_local = jnp.arange(cap * width, dtype=jnp.int32) // width
+                parent = parent.at[out_idx].set(
+                    jnp.take(sel_idx, rows_local), mode="drop")
+                newcol = newcol.at[out_idx].set(cand.reshape(-1),
+                                                mode="drop")
+                return parent, newcol, offset + bucket_total
+
+            # an empty bucket contributes nothing: skip its window gather,
+            # membership and compaction.  A wide bucket is empty in most
+            # chunks and its fixed-shape work would dominate them; without
+            # the skip the served mico-syn triangle does not fit
+            # chip_smoke.py's run (PERF.md §5)
+            if last_enum:
+                total_cnt += jax.lax.cond(
+                    sub_total > 0, bucket_count,
+                    lambda: jnp.asarray(0, jnp.int64))
+            else:
+                parent, newcol, offset = jax.lax.cond(
+                    sub_total > 0, bucket_expand, lambda c: c,
+                    (parent, newcol, offset))
         if last_enum:
             return total_cnt, None, needed
         new_emb = jnp.concatenate(
@@ -425,8 +455,7 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
         us = sub_emb[:, jnp.asarray(U)].T                          # [P, B]
         signed = level_expand(
             comb, flat, indptr[us], degrees[us], None, cvalid,
-            dirs=(), count=True, neg_from=width,
-            window=W, flat_padded=True,
+            dirs=(), count=True, neg_from=width, interpret=interpret,
         )
         return signed.astype(jnp.int64)
 
@@ -438,6 +467,36 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
         iep = plan.iep
         cards = []
         needed_extra = jnp.asarray(0, jnp.int32)
+
+        def bucket_card(U, base, sel_idx, sub_valid, width, card):
+            sub_emb = jnp.take(emb, sel_idx, axis=0, mode="clip")
+            sub_base = jnp.take(base, sel_idx, mode="clip")
+            if use_pallas:
+                val = iep_card_fused(
+                    sub_emb, sub_base, sub_valid, U,
+                    indptr, degrees, flat, width)
+            else:
+                raw = expand_core(
+                    sub_emb, sub_base, sub_valid, U, (),
+                    indptr, degrees, flat, width, want_counts=True,
+                ).astype(jnp.int64)
+                # subtract already-assigned prefix vertices inside
+                # the intersection (injectivity w.r.t. outer loops)
+                corr = jnp.zeros_like(raw)
+                for j in range(depth):
+                    vj = sub_emb[:, j]
+                    inside = sub_valid
+                    for q in U:
+                        u = sub_emb[:, q]
+                        inside &= _segment_member(
+                            flat, indptr[u], indptr[u] + degrees[u],
+                            vj, iters
+                        )
+                    corr += inside.astype(jnp.int64)
+                val = raw - corr
+            return card.at[sel_idx].add(
+                jnp.where(sub_valid, val, 0), mode="drop")
+
         for U in iep.unions:
             base = pick_base(emb, degrees, U)
             db = degrees[base]
@@ -449,33 +508,11 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
                 sel_idx, sub_valid, sub_total = select_rows(rowmask, cap)
                 needed_extra = jnp.maximum(needed_extra,
                                            scaled_need(sub_total, cap))
-                sub_emb = jnp.take(emb, sel_idx, axis=0, mode="clip")
-                sub_base = jnp.take(base, sel_idx, mode="clip")
-                if use_pallas:
-                    val = iep_card_fused(
-                        sub_emb, sub_base, sub_valid, U,
-                        indptr, degrees, flat, width)
-                else:
-                    raw = expand_core(
-                        sub_emb, sub_base, sub_valid, U, (),
-                        indptr, degrees, flat, width, want_counts=True,
-                    ).astype(jnp.int64)
-                    # subtract already-assigned prefix vertices inside
-                    # the intersection (injectivity w.r.t. outer loops)
-                    corr = jnp.zeros_like(raw)
-                    for j in range(depth):
-                        vj = sub_emb[:, j]
-                        inside = sub_valid
-                        for q in U:
-                            u = sub_emb[:, q]
-                            inside &= _segment_member(
-                                flat, indptr[u], indptr[u] + degrees[u],
-                                vj, iters
-                            )
-                        corr += inside.astype(jnp.int64)
-                    val = raw - corr
-                card = card.at[sel_idx].add(
-                    jnp.where(sub_valid, val, 0), mode="drop")
+                # an empty bucket adds nothing (see expand_level)
+                card = jax.lax.cond(
+                    sub_total > 0,
+                    partial(bucket_card, U, base, sel_idx, sub_valid, width),
+                    lambda c: c, card)
             cards.append(card)
         val = jnp.zeros((C,), dtype=jnp.int64)
         for coeff, idxs in iep.terms:
@@ -550,10 +587,9 @@ def device_graph(graph: GraphCSR) -> DeviceGraph:
     by every cached matcher instead of re-uploading per pattern.
 
     The flat indices array is padded ONCE here with never-matching
-    sentinels so the fused kernel's in-grid window DMAs (bounded by the
-    row-extent + DMA-skip invariant — DESIGN.md §4) stay in bounds;
-    every kernel call then passes ``flat_padded=True`` instead of
-    re-padding the resident graph per call.
+    sentinels to a whole number of gather tiles, so the fused kernel's
+    aligned tile DMAs (DESIGN.md §4) read a free [F/128, 128] view of
+    it and no kernel call re-pads the resident graph.
 
     Labeled graphs additionally upload the per-label CSR view: vlabels
     padded to [n+1] with -1 (the frontier's sentinel root n never
@@ -566,7 +602,8 @@ def device_graph(graph: GraphCSR) -> DeviceGraph:
     degrees = np.concatenate([graph.degrees, np.zeros(1, dtype=np.int32)])
     flat = np.concatenate([
         graph.indices,
-        np.full(flat_gather_pad(), np.iinfo(np.int32).max, dtype=np.int32),
+        np.full(flat_gather_pad(len(graph.indices)),
+                np.iinfo(np.int32).max, dtype=np.int32),
     ])
     labs = None
     if graph.labels is not None:
@@ -673,7 +710,7 @@ class Matcher:
         shape = chunk width) is not the one counting will use."""
         width = min(chunk or self.cfg.capacity, self.cfg.capacity)
         v0 = jnp.full((width,), self.graph.n, dtype=jnp.int32)
-        with enable_x64(True):
+        with jax.enable_x64(True):
             jax.block_until_ready(
                 self._fn(self.cfg.capacity)(*self._call_args(), v0))
 
@@ -685,14 +722,10 @@ class Matcher:
         :meth:`install_exported` and skips Python re-tracing entirely;
         escalated capacities still JIT live (they are rare retry paths).
         """
-        from ..compat import jax_export
-
-        if jax_export is None:
-            raise RuntimeError("jax.export unavailable on this JAX version")
         width = min(chunk or self.cfg.capacity, self.cfg.capacity)
         v0 = jnp.full((width,), self.graph.n, dtype=jnp.int32)
-        with enable_x64(True):
-            exported = jax_export.export(self._fn(self.cfg.capacity))(
+        with jax.enable_x64(True):
+            exported = jax.export.export(self._fn(self.cfg.capacity))(
                 *self._call_args(), v0)
         return exported.serialize()
 
@@ -702,11 +735,7 @@ class Matcher:
         fn.  Raises ValueError when the blob targets another platform or
         was traced against different array shapes — callers catch it and
         fall back to a fresh :meth:`warmup` JIT."""
-        from ..compat import jax_export
-
-        if jax_export is None:
-            raise ValueError("jax.export unavailable on this JAX version")
-        exported = jax_export.deserialize(data)
+        exported = jax.export.deserialize(data)
         backend = jax.default_backend()
         if backend not in exported.platforms:
             raise ValueError(
@@ -806,7 +835,7 @@ class Matcher:
         chunk = state.chunk
         budget = None if max_dispatches is None else max(int(max_dispatches),
                                                          1)
-        with enable_x64(True), tr.span(
+        with jax.enable_x64(True), tr.span(
                 "executor.count", depth=self.plan.depth,
                 buckets=cfg.fingerprint(), sync=trace_sync,
                 resumed=state.dispatches > 0) as csp:
@@ -903,10 +932,14 @@ class ShardedMatcher:
         per = math.ceil(graph.n / nshards)
         per = math.ceil(per / self.chunk) * self.chunk  # pad to chunk multiple
         self._per = per
-        # striped: column-major so device d gets d, d+P, 2P+d, ...
+        # striped: column-major so device d gets d, d+P, 2P+d, ...; laid
+        # out over the mesh once here, as the shard_map consumes it
+        from jax.sharding import NamedSharding, PartitionSpec
+
         v0 = np.full(nshards * per, graph.n, dtype=np.int32)
         v0[: graph.n] = np.arange(graph.n, dtype=np.int32)
-        self._v0 = jnp.asarray(v0.reshape(per, nshards).T.reshape(-1))
+        self._v0 = jax.device_put(v0.reshape(per, nshards).T.reshape(-1),
+                                  NamedSharding(mesh, PartitionSpec(axis)))
         self._fns: dict[int, object] = {}     # capacity -> jitted shard fn
         self._capacity = self.cfg.capacity    # sticky escalated capacity
 
@@ -942,7 +975,7 @@ class ShardedMatcher:
                 return jax.lax.psum(tot, axis), jax.lax.pmax(mx, axis)
 
             self._fns[capacity] = jax.jit(
-                shard_map(
+                jax.shard_map(
                     shard_fn,
                     # P() is a pytree PREFIX for the labs tuple: the label
                     # views are replicated like the CSR arrays
@@ -958,7 +991,7 @@ class ShardedMatcher:
         # all-sentinel frontier: compiles the program without doing the
         # real count (mirrors Matcher.warmup)
         v0 = jnp.full_like(self._v0, self.graph.n)
-        with enable_x64(True):
+        with jax.enable_x64(True):
             jax.block_until_ready(
                 self._fn(self.cfg.capacity)(*self._call_args(), v0))
 
@@ -1005,7 +1038,7 @@ class ShardedMatcher:
         with tr.span("executor.count", depth=self.plan.depth,
                      sharded=True, chunk=self.chunk) as csp:
             while True:
-                with enable_x64(True), tr.span(
+                with jax.enable_x64(True), tr.span(
                         "executor.dispatch", capacity=capacity,
                         frontier=int(self._v0.shape[0])) as dsp:
                     cnt, needed = self._fn(capacity)(*call_args, self._v0)

@@ -15,24 +15,23 @@ from .intersect import (
     membership_pallas,
 )
 
-# Largest block_l the level-expansion kernel accepts.  device_graph pads
-# the device CSR by flat_gather_pad() sentinels so every in-kernel
-# window DMA stays in bounds for any block_l up to this.
-MAX_BLOCK_L = 512
+# Tile width of the kernel's neighbor gather: the flat CSR array is read
+# as rows of GATHER_TILE int32s (one lane-row of a vreg), and every DMA
+# starts on such a row.  device_graph pads the resident array to a
+# multiple of it, so the [F/GATHER_TILE, GATHER_TILE] view is free.
+GATHER_TILE = 128
 
 
-def flat_gather_pad() -> int:
-    """Sentinel entries to append to a flat CSR array so the kernel's
-    in-grid window DMAs never read out of bounds.
+def flat_gather_pad(flat_len: int, tile: int = GATHER_TILE) -> int:
+    """Sentinel entries to append to a flat CSR array of `flat_len`
+    entries so it splits into whole `tile`-wide gather tiles.
 
-    Safety invariant (what actually bounds the reads): every row must
-    lie inside the UNPADDED array — starts[p, b] + lens[p, b] ≤ F — as
-    real CSR rows do.  The kernel only DMAs l-blocks with
-    li·block_l < lens[p, b], so the furthest read ends at
-    starts + round_up(lens, block_l) ≤ F + block_l − 1.  A constant
-    MAX_BLOCK_L pad therefore suffices for any row length / `window`,
-    for any block_l ≤ MAX_BLOCK_L (asserted in level_expand)."""
-    return MAX_BLOCK_L
+    Safety invariant (what bounds the reads): every row lies inside the
+    UNPADDED array — starts[p, b] + lens[p, b] ≤ flat_len — as real CSR
+    rows do.  The kernel reads only the aligned tiles that cover a row,
+    so its furthest read ends at round_up(flat_len, tile): the tile
+    holding the row's last entry."""
+    return (-flat_len) % tile
 
 
 def _pad_to(x: jax.Array, mult0: int, mult1: int, value) -> jax.Array:
@@ -53,7 +52,7 @@ def sorted_membership(
     block_b: int = 8,
     block_d: int = 128,
     block_l: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """mask[b, d] = cand[b, d] ∈ nbr[b, :nbr_len[b]] (rows sorted asc).
 
@@ -87,7 +86,7 @@ def intersect_count(
     block_b: int = 8,
     block_d: int = 128,
     block_l: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """cnt[b] = |{d : cand[b,d] ∈ nbr[b,:]}| — fused count kernel.
 
@@ -110,8 +109,7 @@ def intersect_count(
     return out[:B]
 
 
-@partial(jax.jit, static_argnames=("dirs", "count", "neg_from", "window",
-                                   "flat_padded", "block_b", "block_d",
+@partial(jax.jit, static_argnames=("dirs", "count", "neg_from", "block_b",
                                    "block_l", "interpret"))
 def level_expand(
     cand: jax.Array,                      # [B, D] candidate window
@@ -124,12 +122,9 @@ def level_expand(
     dirs: tuple = (),
     count: bool = False,
     neg_from: int | None = None,
-    window: int,
-    flat_padded: bool = False,
     block_b: int = 8,
-    block_d: int = 128,
-    block_l: int = 128,
-    interpret: bool | None = None,
+    block_l: int = GATHER_TILE,
+    interpret: bool = False,
 ) -> jax.Array:
     """One fused, self-feeding Pallas pass for a whole expansion level.
 
@@ -142,51 +137,50 @@ def level_expand(
     fused IEP prefix-correction tail — DESIGN.md §4).
 
     The predecessor neighborhoods are gathered INSIDE the kernel from
-    `flat` (scalar-prefetched `starts`, per-row DMA) — no caller ever
-    materializes a stacked [P, B, W] window array.
+    `flat` in aligned `block_l`-wide tiles (scalar-prefetched offsets,
+    per-row DMA) — no caller ever materializes a stacked [P, B, W]
+    window array.  Rows with no valid candidate get length 0, so the
+    kernel fetches nothing for them.
 
     Contracts:
       * rows flat[starts[p,b] : +lens[p,b]] STRICTLY increasing (CSR
         neighborhoods are) — the per-candidate hit accumulator relies on
         at most one match per predecessor row;
-      * `window` (static) ≥ every lens[p, b] — blocks past it are never
-        walked;
-      * every row lies inside the unpadded array:
-        starts[p, b] + lens[p, b] ≤ len(flat) (CSR rows do) — with the
-        DMA skip this bounds reads to len(flat) + block_l − 1, so
-        flat_gather_pad() sentinels make them safe;
-      * flat_padded=True asserts the caller already appended those
-        sentinels (device_graph does); with False the wrapper pads here
-        (fine for tests, avoid per-call padding of a resident graph on
-        the hot path).
+      * every row lies inside the array: starts[p, b] + lens[p, b] ≤
+        len(flat) (CSR rows do);
+      * a `flat` whose length is not a multiple of block_l is padded
+        here with flat_gather_pad() sentinels — device_graph pads the
+        resident graph once, so the hot path never copies it.
+    `interpret=True` runs the Pallas interpreter (CPU tests); the
+    default lowers to a Mosaic TPU kernel.
     """
     B, D = cand.shape
-    P, _ = starts.shape
     cand = cand.astype(jnp.int32)
     if cand_valid is not None:
         cand = jnp.where(cand_valid, cand, CAND_PAD)
-    cand_p = _pad_to(cand, block_b, block_d, CAND_PAD)
+        # a row with no candidate left needs no neighbor tile at all
+        lens = jnp.where(cand_valid.any(axis=1)[None, :], lens, 0)
+    cand_p = _pad_to(cand, block_b, block_l, CAND_PAD)
     starts = starts.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
     pb = (-B) % block_b
     if pb:
-        # padded rows: offset 0 / length 0 — the kernel skips their DMAs
+        # padded rows: offset 0 / length 0 — the kernel fetches nothing
         starts = jnp.pad(starts, ((0, 0), (0, pb)))
         lens = jnp.pad(lens, ((0, 0), (0, pb)))
     flat = flat.astype(jnp.int32)
-    assert block_l <= MAX_BLOCK_L, (block_l, MAX_BLOCK_L)
-    if not flat_padded:
-        flat = jnp.concatenate(
-            [flat, jnp.full(flat_gather_pad(), NBR_PAD, jnp.int32)])
+    pad = flat_gather_pad(flat.shape[0], block_l)
+    if pad:
+        flat = jnp.concatenate([flat, jnp.full(pad, NBR_PAD, jnp.int32)])
     if dirs:
         extra = extra.astype(jnp.int32)
         if pb:
             extra = jnp.pad(extra, ((0, pb), (0, 0)))
     out = level_expand_pallas(
-        cand_p, flat, starts, lens, extra if dirs else None,
-        dirs=tuple(dirs), count=count, neg_from=neg_from, window=window,
-        block_b=block_b, block_d=block_d, block_l=block_l,
-        interpret=interpret,
+        cand_p, flat.reshape(-1, block_l), starts, lens,
+        extra if dirs else None,
+        dirs=tuple(dirs), count=count, neg_from=neg_from,
+        block_b=block_b, interpret=interpret,
     )
     return out[:B] if count else out[:B, :D]
 

@@ -79,7 +79,6 @@ def flash_kernel_flops(cfg, shape, mesh) -> float:
 def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str, *,
                opts=None):
     """Lower+compile one cell; returns (compiled, model_flops)."""
-    from ..compat import set_mesh
     from ..configs import SHAPES, get_config, input_specs
     from ..models import transformer as T
     from ..serve.serve_step import make_decode, make_prefill
@@ -90,7 +89,7 @@ def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str, *,
     shape = SHAPES[shape_name]
     mf = model_flops_estimate(cfg, shape)
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             batch_shape = input_specs(cfg, shape)
             opts = opts or TrainOptions()
@@ -161,11 +160,10 @@ def lower_graphpi(mesh, mesh_name: str, *, buckets: bool | None = None):
         cnt, needed = count_fn(indptr, degrees, flat, v0_local)
         return jax.lax.psum(cnt, ax), jax.lax.pmax(needed, ax)
 
-    from ..compat import enable_x64, shard_map
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 shard_fn, mesh=mesh,
                 in_specs=(P(), P(), P(), P(ax)),
                 out_specs=(P(), P()),

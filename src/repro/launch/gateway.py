@@ -109,6 +109,7 @@ def main(argv=None):
 
     from ..configs.graphpi import get_dataset, get_pattern
     from ..core.executor import ExecutorConfig, auto_buckets, compute_stats
+    from ..launch.compile_cache import enable_compile_cache
     from ..launch.mesh import shared_host_mesh
     from ..launch.query_serve import build_requests
     from ..obs import MetricsRegistry
@@ -118,6 +119,7 @@ def main(argv=None):
     )
     from ..serve.session import LMSession
 
+    enable_compile_cache()
     start_tracing(args)
 
     if args.warm_from_disk and not args.cache_dir:
@@ -133,7 +135,8 @@ def main(argv=None):
     if not args.single_device and len(jax.devices()) > 1:
         graph_mesh = mesh
 
-    cfg = ExecutorConfig(capacity=args.capacity)
+    cfg = ExecutorConfig(capacity=args.capacity,
+                         degree_buckets=auto_buckets(graph))
     stats = None
     if args.model_buckets:
         stats = compute_stats(graph, cfg)

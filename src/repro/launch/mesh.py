@@ -1,32 +1,32 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state (the dry-run sets XLA_FLAGS before first jax init).  Mesh
-construction goes through repro.compat.make_mesh, which drops the
-axis-types kwarg on JAX releases that predate jax.sharding.AxisType
-(every axis is implicitly auto there — the semantics we want)."""
+state (the dry-run sets XLA_FLAGS before first jax init).  Every axis
+is `AxisType.Auto`: the compiler places the collectives."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from ..compat import make_mesh
+
+def make_mesh(shape, axis_names):
+    """`jax.make_mesh` over the process's devices, all axes Auto."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod meshes: (16, 16) single pod, (2, 16, 16) two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=("auto",) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Mesh over whatever devices exist (CI/local): data × model."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return make_mesh(
-        (n // model, model), ("data", "model"),
-        axis_types=("auto", "auto"),
-    )
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 _SHARED_MESHES: dict[int, object] = {}

@@ -46,10 +46,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from ..configs.graphpi import get_dataset, get_pattern
-    from ..core.executor import ExecutorConfig
+    from ..core.executor import ExecutorConfig, auto_buckets
+    from ..launch.compile_cache import enable_compile_cache
     from ..launch.mesh import make_host_mesh
     from ..query import PlanStore, QueryEngine, QueryRequest
 
+    enable_compile_cache()
     start_tracing(args)
 
     pattern = get_pattern(args.pattern)
@@ -62,8 +64,9 @@ def main(argv=None):
     if not args.single_device and len(jax.devices()) > 1:
         mesh = make_host_mesh(model=args.model_axis)
     store = PlanStore(args.cache_dir) if args.cache_dir else None
-    engine = QueryEngine(graph, cfg=ExecutorConfig(capacity=args.capacity),
-                         mesh=mesh, store=store)
+    cfg = ExecutorConfig(capacity=args.capacity,
+                         degree_buckets=auto_buckets(graph))
+    engine = QueryEngine(graph, cfg=cfg, mesh=mesh, store=store)
     print(f"[mine] stats: tri_cnt={engine.stats.tri_cnt} "
           f"({engine.stats_seconds:.2f}s)")
 

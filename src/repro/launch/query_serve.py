@@ -126,12 +126,14 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from ..configs.graphpi import get_dataset, get_pattern
-    from ..core.executor import ExecutorConfig
+    from ..core.executor import ExecutorConfig, auto_buckets
+    from ..launch.compile_cache import enable_compile_cache
     from ..launch.mesh import shared_host_mesh
     from ..obs import MetricsRegistry
     from ..query import PlanCache, PlanStore, QueryEngine, canonical_key
     from ..serve.gateway import Gateway, GraphQueryWorkload, Share
 
+    enable_compile_cache()
     start_tracing(args)
 
     if args.warm_from_disk and not args.cache_dir:
@@ -147,7 +149,8 @@ def main(argv=None):
     metrics = MetricsRegistry()
     engine = QueryEngine(
         graph,
-        cfg=ExecutorConfig(capacity=args.capacity),
+        cfg=ExecutorConfig(capacity=args.capacity,
+                           degree_buckets=auto_buckets(graph)),
         mesh=mesh,
         chunk=args.chunk or None,
         cache=PlanCache(max_entries=args.max_entries or None, store=store),

@@ -40,7 +40,6 @@ def main(argv=None):
 
     from ..configs import get_config, get_smoke_config, input_specs
     from ..configs.base import ShapeConfig
-    from ..compat import set_mesh
     from ..launch.mesh import make_host_mesh
     from ..train import checkpoint as ckpt
     from ..train.data import DataConfig, SyntheticLM
@@ -56,7 +55,7 @@ def main(argv=None):
     opt_cfg = AdamWConfig(lr=args.lr, total_steps=args.steps,
                           warmup_steps=max(args.steps // 20, 5))
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         step_fn, p_sh, o_sh, b_sh = make_train_step(
             cfg, opt_cfg, mesh,
             TrainOptions(remat=True, q_chunk=0, loss_chunk=0,

@@ -201,10 +201,9 @@ def _sdpa_chunked(q, k, v, *, causal: bool, chunk: int = 1024):
 
 def _ambient_model_axis():
     """(model_axis_size, dp_axes) from the ambient mesh, or (1, ())."""
-    from ..compat import get_abstract_mesh
 
     try:
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
     except Exception:  # pragma: no cover
         return 1, ()
     if mesh is None or not getattr(mesh, "axis_names", None):
@@ -276,9 +275,8 @@ def _flash_sharded(q, k, v, *, causal: bool):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import get_abstract_mesh, shard_map
 
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     ndp = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
     if dp and B % ndp:
         dp = dp[:-1]
@@ -301,13 +299,13 @@ def _flash_sharded(q, k, v, *, causal: bool):
             vl = jnp.take(vl, kvidx, axis=2)
             return flash_attention(ql, kl, vl, causal=causal)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
             out_specs=qspec, check_vma=False,
         )(q, k, v)
 
     spec = P(bspec, None, None, None)
-    return shard_map(
+    return jax.shard_map(
         lambda ql, kl, vl: flash_attention(ql, kl, vl, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,

@@ -24,10 +24,13 @@ on-disk index — a persisted entry skips the configuration search
 entirely and, when an AOT executable is present and accepted, skips
 Python re-tracing too (`persist_hits` / `aot_loads` counters); a full
 miss writes the searched result back after warmup (`export_fails`
-counts AOT serialization failures — the entry still persists plan-only).
+counts AOT serialization failures — the entry still persists plan-only,
+and a RuntimeWarning names the cause; an executable the running backend
+rejects counts in `aot_load_fails` and warns the same way).
 """
 from __future__ import annotations
 
+import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, replace as dc_replace
 
@@ -270,9 +273,13 @@ class PlanCache:
                     try:
                         exec_bytes = matcher.export_bytes(chunk=chunk)
                         matcher.install_exported(exec_bytes, chunk=chunk)
-                    except Exception:
+                    except Exception as e:  # noqa: BLE001 — plan-only
                         self.stats.export_fails += 1
                         exec_bytes = None
+                        warnings.warn(
+                            f"AOT export of {key[0]} failed, the entry "
+                            f"persists plan-only: {type(e).__name__}: {e}",
+                            RuntimeWarning, stacklevel=2)
                 if mesh is not None:
                     matcher.warmup()      # chunk is baked into the stripes
                 else:
@@ -317,8 +324,11 @@ class PlanCache:
                 try:
                     matcher.install_exported(rec.exec_bytes, chunk=chunk)
                     installed = True
-                except Exception:
+                except ValueError as e:     # wrong platform or shapes
                     self.stats.aot_load_fails += 1
+                    warnings.warn(
+                        f"AOT executable of {key[0]} rejected, re-JIT: {e}",
+                        RuntimeWarning, stacklevel=2)
             with get_tracer().span("cache.warm", canon_key=key[0],
                                    aot=installed), timer() as t:
                 if mesh is not None:

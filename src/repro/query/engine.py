@@ -776,6 +776,7 @@ class QueryEngine:
     def summary(self) -> dict:
         out = {
             "graph": self.graph.name,
+            "executor": self.cfg.fingerprint(),
             "devices": 1 if self.mesh is None else int(
                 np.prod(list(self.mesh.shape.values()))),
             "stats_seconds": self.stats_seconds,

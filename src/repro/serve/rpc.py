@@ -144,7 +144,8 @@ class GatewayRPCServer:
     calls `gateway.run_round()` (one bounded scheduler round), then
     yields to the sockets; when everything is drained it sleeps on a
     work event that `submit` sets.  `serve_forever()` returns after a
-    `shutdown` frame (or `stop()`)."""
+    `shutdown` frame (or `stop()`), and raises what a failed round
+    raised."""
 
     def __init__(self, gateway, workload, *, host: str = "127.0.0.1",
                  port: int = 0, get_pattern=None):
@@ -180,13 +181,21 @@ class GatewayRPCServer:
         if on_ready is not None:
             on_ready(self.host, self.port)
         drive = asyncio.get_event_loop().create_task(self._drive())
+        stop = asyncio.ensure_future(self._stop_ev.wait())
         try:
-            await self._stop_ev.wait()
+            # a round that raises ends the drive task: stop serving and
+            # re-raise below instead of parking result waiters forever
+            await asyncio.wait({drive, stop},
+                               return_when=asyncio.FIRST_COMPLETED)
         finally:
+            stop.cancel()
             drive.cancel()
             self._pulse()            # release any parked result waiters
             server.close()
             await server.wait_closed()
+        if (drive.done() and not drive.cancelled()
+                and drive.exception() is not None):
+            raise drive.exception()
 
     async def _drive(self) -> None:
         while not self._stop_ev.is_set():

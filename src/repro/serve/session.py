@@ -151,12 +151,11 @@ class LMSession:
         """Prefill — or, with `resume=True` and a checkpoint present,
         restore cache/tokens/step and skip the prefill entirely.
         Returns the restored step (None for a fresh start)."""
-        from ..compat import set_mesh
         from ..models import transformer as T
         from .serve_step import make_decode
 
         key = jax.random.PRNGKey(self.seed)
-        with set_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             # param init + decode-program build dominate cold start; a
             # leaf span keeps warmup time attributable in traces
             with get_tracer().span("lm.init", arch=self.arch,
@@ -263,7 +262,6 @@ class LMSession:
         recorded, and their cache rows are re-seeded on admit())."""
         if self._decode is None:
             raise RuntimeError("LMSession.start() must run first")
-        from ..compat import set_mesh
         from ..train import checkpoint as ckpt
 
         n = min(max(k, 0), self.remaining)
@@ -271,7 +269,7 @@ class LMSession:
             return 0
         with get_tracer().span("lm.decode", arch=self.arch, steps=n,
                                at_step=self.step_i), timer() as t:
-            with set_mesh(self.mesh):
+            with jax.sharding.set_mesh(self.mesh):
                 for _ in range(n):
                     i = self.step_i
                     pos = jnp.asarray(self._pos)
@@ -324,10 +322,9 @@ class LMSession:
         slot = free[0]
         if seed is None:
             seed = self.seed + 1009 * (self.admitted + 1)
-        from ..compat import set_mesh
 
         with get_tracer().span("lm.admit", slot=slot, seed=seed), \
-                set_mesh(self.mesh), timer() as t:
+                jax.sharding.set_mesh(self.mesh), timer() as t:
             row_cache, token = self._prefill_one(seed)
             self._cache = jax.block_until_ready(jax.tree.map(
                 lambda dst, src: _scatter_row(dst, src, slot),

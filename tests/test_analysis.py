@@ -243,16 +243,6 @@ def test_lint_no_raw_timing():
     assert not lint_source(src, "core/config_search.py")
 
 
-def test_lint_compat_only_drift():
-    src = ("import jax\nfrom jax.experimental import shard_map\n"
-           "from jax.experimental import pallas\n"
-           "def f():\n    return jax.sharding.set_mesh\n")
-    f = lint_source(src, "models/layers.py")
-    assert {x.rule for x in f} == {"compat-only-drift"}
-    assert len(f) == 2                      # pallas import stays allowed
-    assert not lint_source(src, "repro/compat.py")   # shim home is exempt
-
-
 def test_lint_no_stale_fingerprint():
     src = ("class Engine:\n"
            "    def __init__(self, graph, stats):\n"
@@ -297,23 +287,24 @@ def test_lint_tracer_concretize():
 
 # ------------------------------------------------------- kernel contracts
 def test_kernel_spec_clean_and_violations():
-    ok = LevelExpandSpec(B=64, D=16, P=2, E=2, window=16, flat_len=512)
+    ok = LevelExpandSpec(B=64, D=16, P=2, E=2, flat_len=512)
     assert not check_spec(ok)
-    dma = dataclasses.replace(ok, block_l=1024)
+    dma = dataclasses.replace(ok, block_l=384)     # not a power of two
     assert any(f.rule == "kernel-dma-window" for f in check_spec(dma))
-    blk = dataclasses.replace(ok, block_d=100)
+    blk = dataclasses.replace(ok, block_b=6)
     assert any(f.rule == "kernel-block-shape" for f in check_spec(blk))
     of = dataclasses.replace(ok, flat_len=2**31 - 10)
     assert any(f.rule == "kernel-int32-offset" for f in check_spec(of))
+    smem = dataclasses.replace(ok, P=5000)
+    assert any(f.rule == "kernel-smem" for f in check_spec(smem))
 
 
 def test_kernel_abstract_eval_clean():
     for spec in (
-        LevelExpandSpec(B=64, D=16, P=2, E=2, window=16, flat_len=512),
-        LevelExpandSpec(B=64, D=16, P=2, E=1, window=16, flat_len=512,
-                        count=True),
-        LevelExpandSpec(B=64, D=20, P=2, window=16, flat_len=512,
-                        count=True, neg_from=16),
+        LevelExpandSpec(B=64, D=16, P=2, E=2, flat_len=512),
+        LevelExpandSpec(B=64, D=16, P=2, E=1, flat_len=512, count=True),
+        LevelExpandSpec(B=64, D=20, P=2, flat_len=512, count=True,
+                        neg_from=16),
     ):
         findings = abstract_eval_spec(spec)
         assert not has_errors(findings), format_findings(findings)

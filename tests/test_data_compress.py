@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
 from repro.configs import get_smoke_config
 from repro.train.compress import compressed_psum, dequantize_int8, quantize_int8
 from repro.train.data import DataConfig, SyntheticLM
@@ -66,7 +65,7 @@ def test_compressed_psum_single_device_identity_with_error_feedback():
         return out, err
 
     out, err = jax.jit(
-        shard_map(f, mesh=mesh,
+        jax.shard_map(f, mesh=mesh,
                   in_specs=(jax.sharding.PartitionSpec(),),
                   out_specs=(jax.sharding.PartitionSpec(),) * 2)
     )(g)
@@ -88,7 +87,7 @@ def test_error_feedback_accumulates_to_true_sum():
         out, err = compressed_psum(t, "dp", error_state=e)
         return out, err
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         f, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(),) * 2,
         out_specs=(jax.sharding.PartitionSpec(),) * 2,

@@ -1,17 +1,22 @@
 """Per-kernel validation: sweep shapes/dtypes, assert against ref.py.
 
-Kernels run in interpret=True on CPU (the container has no TPU); the
+Kernels run in the Pallas interpreter (interpret=True) on the CPU; the
 BlockSpec tiling and grid logic are identical to the hardware path.
 """
+from functools import partial
+
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.ops import intersect_count, sorted_membership
+from repro.kernels import ops
 from repro.kernels.ref import (
     intersect_count_ref, membership_ref, membership_ref_searchsorted,
 )
+
+sorted_membership = partial(ops.sorted_membership, interpret=True)
+intersect_count = partial(ops.intersect_count, interpret=True)
 
 
 def _mk(rng, B, D, L, dtype, hi=2000):

@@ -2,7 +2,8 @@
 
 1. Kernel vs the pure-jnp oracle (kernels/ref.py) on random CSR-layout
    windows — the kernel gathers every predecessor neighborhood from the
-   flat array INSIDE the grid (scalar-prefetched offsets + per-row DMA),
+   flat array INSIDE the grid (scalar-prefetched offsets + per-row DMA
+   of the aligned tiles that cover the row),
    so these tests feed (flat, starts, lens), never a stacked [P, B, W]
    array.  Mask and count modes, ragged/empty rows, all three comparison
    kinds (restriction >, restriction <, injectivity !=), and the signed
@@ -66,7 +67,7 @@ def test_level_expand_matches_ref(seed, count):
     cand, flat, starts, lens, extra, valid = _csr_windows(seed)
     dirs = (1, -1, 0)
     got = ops.level_expand(cand, flat, starts, lens, extra, valid,
-                           dirs=dirs, count=count, window=50)
+                           dirs=dirs, count=count, interpret=True)
     want = ref.level_expand_ref(cand, flat, starts, lens, extra, valid,
                                 dirs=dirs, count=count, window=50)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -79,7 +80,7 @@ def test_level_expand_signed_count_matches_ref(seed, neg_from):
     corrections ride along as negatively-weighted candidates)."""
     cand, flat, starts, lens, _, valid = _csr_windows(seed)
     got = ops.level_expand(cand, flat, starts, lens, None, valid,
-                           count=True, neg_from=neg_from, window=50)
+                           count=True, neg_from=neg_from, interpret=True)
     want = ref.level_expand_ref(cand, flat, starts, lens, None, valid,
                                 count=True, neg_from=neg_from, window=50)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -87,36 +88,38 @@ def test_level_expand_signed_count_matches_ref(seed, neg_from):
 
 def test_level_expand_no_extras_single_pred():
     cand, flat, starts, lens, _, valid = _csr_windows(3, P=1)
-    got = ops.level_expand(cand, flat, starts, lens, None, valid, window=50)
+    got = ops.level_expand(cand, flat, starts, lens, None, valid,
+                           interpret=True)
     want = ref.level_expand_ref(cand, flat, starts, lens, None, valid,
                                 window=50)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_level_expand_all_rows_empty():
-    """Empty neighborhoods: no DMA is issued at all, nothing matches."""
+    """Empty neighborhoods: no tile is fetched at all, nothing matches."""
     cand, flat, starts, lens, _, valid = _csr_windows(5, P=2)
     lens[:] = 0
     got = ops.level_expand(cand, flat, starts, lens, None, valid,
-                           count=True, window=50)
+                           count=True, interpret=True)
     assert not np.asarray(got).any()
     got_m = ops.level_expand(cand, flat, starts, lens, None, valid,
-                             window=50)
+                             interpret=True)
     assert not np.asarray(got_m).any()
 
 
 def test_level_expand_block_shape_invariance():
-    """Block layout must not change results (grid/accumulator/DMA-skip
-    logic) — including block_l larger than most row lengths."""
+    """Block layout must not change results (grid/accumulator/tile-walk
+    logic) — including tiles wider than most rows and rows that straddle
+    several narrow tiles."""
     cand, flat, starts, lens, extra, valid = _csr_windows(
         4, B=16, D=40, P=2, L=70)
     dirs = (1, 0, 0)
     base = np.asarray(ref.level_expand_ref(
         cand, flat, starts, lens, extra, valid, dirs=dirs, window=70))
-    for bb, bd, bl in [(8, 128, 128), (4, 64, 32), (16, 256, 256)]:
+    for bb, bl in [(8, 128), (4, 32), (16, 256)]:
         got = ops.level_expand(cand, flat, starts, lens, extra, valid,
-                               dirs=dirs, window=70,
-                               block_b=bb, block_d=bd, block_l=bl)
+                               dirs=dirs, block_b=bb, block_l=bl,
+                               interpret=True)
         np.testing.assert_array_equal(np.asarray(got), base)
 
 
