@@ -38,11 +38,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graph.csr import GraphCSR
-from ..obs import get_tracer
+from ..obs import get_tracer, set_annotator
 from .pattern import Pattern, clique
 from .perf_model import GraphStats
 from .plan import MatchingPlan, build_plan
 from .restrictions import generate_restriction_sets
+
+
+def _profiler_annotation(name: str):
+    """A host event of the profiler's own trace for a program span, while
+    a profiler capture records: program spans and device ops then share
+    one clock in the capture."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name)
+    return None
+
+
+set_annotator(_profiler_annotation)
 
 
 # --------------------------------------------------------------------------
@@ -165,6 +177,43 @@ def auto_buckets(graph, *, small: int = 128, mid: int = 1024,
     return tuple(out)
 
 
+def _bucket_layout(cfg: ExecutorConfig, W: int) -> tuple:
+    """Normalized ((width, frac), ...) bucket layout; None collapses to
+    the degenerate single max-degree window so there is exactly ONE
+    expansion path."""
+    buckets = cfg.degree_buckets
+    if buckets is None:
+        return ((W, 1.0),)
+    buckets = tuple((min(int(w), W), float(f)) for (w, f) in buckets)
+    if buckets[-1][0] < W:
+        buckets = buckets + ((W, buckets[-1][1]),)
+    return buckets
+
+
+def _bucket_ranges(buckets: tuple, C: int):
+    """(index, width, cap, lower degree bound, is_last) of each bucket at
+    frontier capacity C."""
+    lo = 0
+    for bi, (w, f) in enumerate(buckets):
+        yield bi, w, max(int(C * f), 8), lo, bi == len(buckets) - 1
+        lo = w
+
+
+def fill_slots(plan: MatchingPlan, W: int,
+               cfg: ExecutorConfig) -> list[tuple[str, int, int]]:
+    """(scope, cap, width) of every candidate window the count program
+    may launch, in the order of the rows of its `fill` output: each
+    expansion level (`level{i}`), then each IEP union (`iep{k}`), each
+    over the bucket layout.  The scope is the window's `jax.named_scope`
+    (`level{i}/w{width}`), so its device ops carry it in a profile."""
+    steps = [f"level{i}" for i in range(1, plan.depth)]
+    if plan.iep is not None:
+        steps += [f"iep{k}" for k in range(len(plan.iep.unions))]
+    ranges = list(_bucket_ranges(_bucket_layout(cfg, W), cfg.capacity))
+    return [(f"{step}/w{w}", cap, w) for step in steps
+            for _, w, cap, _, _ in ranges]
+
+
 @dataclass
 class CountResult:
     count: int
@@ -194,22 +243,21 @@ class CountState:
 # single-shard counting kernel (pure function of device arrays; jit-safe)
 # --------------------------------------------------------------------------
 def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
-                   cfg: ExecutorConfig, *, level_cb=None):
-    """Returns count(indptr, degrees, flat, v0) -> (count i64, needed i32)
-    — or, for labeled plans, count(indptr, degrees, flat, labs, v0) where
-    `labs` = (vlabels [n+1], lab_starts [n+1, L], lab_lens [n+1, L],
-    lab_flat) are the device per-label CSR views (see `device_graph`).
+                   cfg: ExecutorConfig):
+    """Returns count(indptr, degrees, flat, v0) -> (count i64, needed
+    i32, fill i64[K, 2]) — or, for labeled plans, count(indptr, degrees,
+    flat, labs, v0) where `labs` = (vlabels [n+1], lab_starts [n+1, L],
+    lab_lens [n+1, L], lab_flat) are the device per-label CSR views (see
+    `device_graph`).
+
+    `fill` row k belongs to window k of `fill_slots(plan, W, cfg)`: the
+    rows that window holds (0 where its bucket is empty and the window
+    is skipped) and their valid entries, the sum of their base degrees
+    (or label-segment lengths).  It is computed on every run, so a
+    traced run executes the program an untraced one does.
 
     `W` = candidate-window width (graph max degree), static.
     `degrees` must be padded to [n+1] with 0 at index n (sentinel).
-
-    `level_cb` (keyword-only) hooks per-level observability: when given,
-    every schedule level runs as ``level_cb(i, thunk)`` where `thunk`
-    computes that level's `expand_level` (or the IEP tail, `i="iep"`) —
-    the callback wraps it in a span and may fence on the results.  Only
-    meaningful on an EAGER (un-jitted) count fn: under jit the callback
-    would fire once at trace time with abstract values, so the Matcher
-    only routes here on the `--trace-sync` path.
     """
     n = plan.n
     depth = plan.depth
@@ -219,15 +267,7 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
     # Static per-position label requirements (None = wildcard / unlabeled).
     vlabels = plan.vlabels or (None,) * n
 
-    # Normalized bucket layout; None collapses to the degenerate single
-    # max-degree window so there is exactly ONE expansion path.
-    buckets = cfg.degree_buckets
-    if buckets is not None:
-        buckets = tuple((min(int(w), W), float(f)) for (w, f) in buckets)
-        if buckets[-1][0] < W:
-            buckets = buckets + ((W, buckets[-1][1]),)
-    else:
-        buckets = ((W, 1.0),)
+    buckets = _bucket_layout(cfg, W)
 
     def gather_window(flat, indptr, degrees, base, width, *, labs=None,
                       label=None):
@@ -334,9 +374,11 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
             return mask.sum(axis=1).astype(jnp.int32)
         return cand, mask
 
-    def select_rows(rowmask, cap):
+    def select_rows(rowmask, cap, db):
         """Compact indices of rows where rowmask → (sel_idx [cap] with C as
-        the drop sentinel, sub_valid [cap], sub_total)."""
+        the drop sentinel, sub_valid [cap], sub_total, fill), where fill
+        is the (rows, valid entries) of the window: the first `cap` such
+        rows and the sum of their base degrees `db`."""
         pos = jnp.cumsum(rowmask) - 1
         total = (pos[-1] + 1).astype(jnp.int32)
         out_idx = jnp.where(rowmask, jnp.minimum(pos, cap), cap)
@@ -344,7 +386,11 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
         sel = sel.at[out_idx].set(jnp.arange(C, dtype=jnp.int32),
                                   mode="drop")
         sub_valid = jnp.arange(cap) < total
-        return sel[:cap], sub_valid, total
+        fill = jnp.stack([
+            jnp.minimum(total, cap).astype(jnp.int64),
+            jnp.sum(jnp.where(rowmask & (pos < cap), db, 0),
+                    dtype=jnp.int64)])
+        return sel[:cap], sub_valid, total, fill
 
     def scaled_need(sub_total, cap):
         """Escalation units: sub_total scaled to full-capacity terms so the
@@ -352,16 +398,10 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
         st = sub_total.astype(jnp.int64)
         return ((st * C + cap - 1) // cap).astype(jnp.int32)
 
-    def bucket_ranges():
-        lo = 0
-        for bi, (w, f) in enumerate(buckets):
-            cap = max(int(C * f), 8)
-            yield bi, w, cap, lo, bi == len(buckets) - 1
-            lo = w
-
-    def expand_level(i, emb, valid, needed, indptr, degrees, flat,
-                     labs=None):
-        """One level of frontier expansion over the bucket layout.
+    def expand_level(i, emb, valid, needed, indptr, degrees, flat, labs,
+                     fills):
+        """One level of frontier expansion over the bucket layout; each
+        bucket's window fill is appended to `fills`.
 
         Returns (new_emb, new_valid, needed) — or, at the last
         enumeration level, (count_contribution, None, needed)."""
@@ -375,58 +415,63 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
         newcol = jnp.zeros((C + 1,), dtype=jnp.int32)
         offset = jnp.asarray(0, jnp.int32)
         total_cnt = jnp.asarray(0, jnp.int64)
-        for bi, width, cap, lo, is_last in bucket_ranges():
-            rowmask = valid & (db > lo)
-            if not is_last:
-                rowmask &= db <= width
-            sel_idx, sub_valid, sub_total = select_rows(rowmask, cap)
-            needed = jnp.maximum(needed, scaled_need(sub_total, cap))
+        for bi, width, cap, lo, is_last in _bucket_ranges(buckets, C):
+            with jax.named_scope(f"level{i}/w{width}"):
+                rowmask = valid & (db > lo)
+                if not is_last:
+                    rowmask &= db <= width
+                sel_idx, sub_valid, sub_total, fill = select_rows(
+                    rowmask, cap, db)
+                fills.append(fill)
+                needed = jnp.maximum(needed, scaled_need(sub_total, cap))
 
-            def bucket_count(sel_idx=sel_idx, sub_valid=sub_valid,
-                             width=width):
-                cnts = expand_core(
-                    jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i],
-                    jnp.take(base_all, sel_idx, mode="clip"), sub_valid,
-                    preds, extras, indptr, degrees, flat, width,
-                    want_counts=True, labs=labs, label=label,
-                )
-                return jnp.sum(cnts, dtype=jnp.int64)
+                def bucket_count(sel_idx=sel_idx, sub_valid=sub_valid,
+                                 width=width):
+                    cnts = expand_core(
+                        jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i],
+                        jnp.take(base_all, sel_idx, mode="clip"),
+                        sub_valid, preds, extras, indptr, degrees, flat,
+                        width, want_counts=True, labs=labs, label=label,
+                    )
+                    return jnp.sum(cnts, dtype=jnp.int64)
 
-            def bucket_expand(carry, sel_idx=sel_idx, sub_valid=sub_valid,
-                              width=width, cap=cap):
-                parent, newcol, offset = carry
-                cand, mask = expand_core(
-                    jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i],
-                    jnp.take(base_all, sel_idx, mode="clip"), sub_valid,
-                    preds, extras, indptr, degrees, flat, width,
-                    labs=labs, label=label,
-                )
-                # stream-compact surviving (row, cand) pairs behind `offset`
-                flat_mask = mask.reshape(-1)
-                pos = jnp.cumsum(flat_mask) - 1
-                bucket_total = (pos[-1] + 1).astype(jnp.int32)
-                out_idx = jnp.where(flat_mask,
-                                    jnp.minimum(offset + pos, C), C)
-                rows_local = jnp.arange(cap * width, dtype=jnp.int32) // width
-                parent = parent.at[out_idx].set(
-                    jnp.take(sel_idx, rows_local), mode="drop")
-                newcol = newcol.at[out_idx].set(cand.reshape(-1),
-                                                mode="drop")
-                return parent, newcol, offset + bucket_total
+                def bucket_expand(carry, sel_idx=sel_idx,
+                                  sub_valid=sub_valid, width=width, cap=cap):
+                    parent, newcol, offset = carry
+                    cand, mask = expand_core(
+                        jnp.take(emb, sel_idx, axis=0, mode="clip")[:, :i],
+                        jnp.take(base_all, sel_idx, mode="clip"),
+                        sub_valid, preds, extras, indptr, degrees, flat,
+                        width, labs=labs, label=label,
+                    )
+                    # stream-compact surviving (row, cand) pairs behind
+                    # `offset`
+                    flat_mask = mask.reshape(-1)
+                    pos = jnp.cumsum(flat_mask) - 1
+                    bucket_total = (pos[-1] + 1).astype(jnp.int32)
+                    out_idx = jnp.where(flat_mask,
+                                        jnp.minimum(offset + pos, C), C)
+                    rows_local = (jnp.arange(cap * width, dtype=jnp.int32)
+                                  // width)
+                    parent = parent.at[out_idx].set(
+                        jnp.take(sel_idx, rows_local), mode="drop")
+                    newcol = newcol.at[out_idx].set(cand.reshape(-1),
+                                                    mode="drop")
+                    return parent, newcol, offset + bucket_total
 
-            # an empty bucket contributes nothing: skip its window gather,
-            # membership and compaction.  A wide bucket is empty in most
-            # chunks and its fixed-shape work would dominate them; without
-            # the skip the served mico-syn triangle does not fit
-            # chip_smoke.py's run (PERF.md §5)
-            if last_enum:
-                total_cnt += jax.lax.cond(
-                    sub_total > 0, bucket_count,
-                    lambda: jnp.asarray(0, jnp.int64))
-            else:
-                parent, newcol, offset = jax.lax.cond(
-                    sub_total > 0, bucket_expand, lambda c: c,
-                    (parent, newcol, offset))
+                # an empty bucket contributes nothing: skip its window
+                # gather, membership and compaction.  A wide bucket is
+                # empty in most chunks and its fixed-shape work would
+                # dominate them; without the skip the served mico-syn
+                # triangle does not fit chip_smoke.py's run (PERF.md §5)
+                if last_enum:
+                    total_cnt += jax.lax.cond(
+                        sub_total > 0, bucket_count,
+                        lambda: jnp.asarray(0, jnp.int64))
+                else:
+                    parent, newcol, offset = jax.lax.cond(
+                        sub_total > 0, bucket_expand, lambda c: c,
+                        (parent, newcol, offset))
         if last_enum:
             return total_cnt, None, needed
         new_emb = jnp.concatenate(
@@ -459,11 +504,12 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
         )
         return signed.astype(jnp.int64)
 
-    def iep_value(emb, valid, indptr, degrees, flat):
+    def iep_value(emb, valid, indptr, degrees, flat, fills):
         """Per-row IEP count over the folded tail (int64), with bucketed
         union-window gathers through the shared expansion core.  On the
         Pallas path each (union, bucket) cardinality — including the
-        prefix corrections — is one fused kernel pass."""
+        prefix corrections — is one fused kernel pass.  Each window's
+        fill is appended to `fills`."""
         iep = plan.iep
         cards = []
         needed_extra = jnp.asarray(0, jnp.int32)
@@ -497,22 +543,26 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
             return card.at[sel_idx].add(
                 jnp.where(sub_valid, val, 0), mode="drop")
 
-        for U in iep.unions:
+        for k, U in enumerate(iep.unions):
             base = pick_base(emb, degrees, U)
             db = degrees[base]
             card = jnp.zeros((C,), jnp.int64)
-            for bi, width, cap, lo, is_last in bucket_ranges():
-                rowmask = valid & (db > lo)
-                if not is_last:
-                    rowmask &= db <= width
-                sel_idx, sub_valid, sub_total = select_rows(rowmask, cap)
-                needed_extra = jnp.maximum(needed_extra,
-                                           scaled_need(sub_total, cap))
-                # an empty bucket adds nothing (see expand_level)
-                card = jax.lax.cond(
-                    sub_total > 0,
-                    partial(bucket_card, U, base, sel_idx, sub_valid, width),
-                    lambda c: c, card)
+            for bi, width, cap, lo, is_last in _bucket_ranges(buckets, C):
+                with jax.named_scope(f"iep{k}/w{width}"):
+                    rowmask = valid & (db > lo)
+                    if not is_last:
+                        rowmask &= db <= width
+                    sel_idx, sub_valid, sub_total, fill = select_rows(
+                        rowmask, cap, db)
+                    fills.append(fill)
+                    needed_extra = jnp.maximum(needed_extra,
+                                               scaled_need(sub_total, cap))
+                    # an empty bucket adds nothing (see expand_level)
+                    card = jax.lax.cond(
+                        sub_total > 0,
+                        partial(bucket_card, U, base, sel_idx, sub_valid,
+                                width),
+                        lambda c: c, card)
             cards.append(card)
         val = jnp.zeros((C,), dtype=jnp.int64)
         for coeff, idxs in iep.terms:
@@ -536,21 +586,24 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
             emb = jnp.pad(emb, ((0, C - T), (0, 0)))
             valid = jnp.pad(valid, (0, C - T))
         needed = jnp.asarray(T, dtype=jnp.int32)
+        fills = []
+
+        def fill():
+            if not fills:
+                return jnp.zeros((0, 2), jnp.int64)
+            return jnp.stack(fills)
+
         for i in range(1, depth):
-            thunk = partial(expand_level, i, emb, valid, needed,
-                            indptr, degrees, flat, labs)
-            out, new_valid, needed = (
-                thunk() if level_cb is None else level_cb(i, thunk))
+            out, new_valid, needed = expand_level(
+                i, emb, valid, needed, indptr, degrees, flat, labs, fills)
             if new_valid is None:          # last enumeration level
-                return out, needed
+                return out, needed, fill()
             emb, valid = out, new_valid
         if plan.iep is None:
             # depth-1 == 0: single-vertex pattern — count valid v0 rows
-            return jnp.sum(valid, dtype=jnp.int64), needed
-        iep_thunk = partial(iep_value, emb, valid, indptr, degrees, flat)
-        vals, need2 = (iep_thunk() if level_cb is None
-                       else level_cb("iep", iep_thunk))
-        return jnp.sum(vals), jnp.maximum(needed, need2)
+            return jnp.sum(valid, dtype=jnp.int64), needed, fill()
+        vals, need2 = iep_value(emb, valid, indptr, degrees, flat, fills)
+        return jnp.sum(vals), jnp.maximum(needed, need2), fill()
 
     if plan.vlabels is None:
         # unlabeled plans keep the historical 4-arg signature (AOT blobs,
@@ -650,7 +703,6 @@ class Matcher:
         self.cfg = cfg or ExecutorConfig()
         self._W = max(graph.max_degree, 1)
         self._fns: dict[int, object] = {}     # capacity -> jitted count_fn
-        self._traced_fns: dict[int, object] = {}  # eager --trace-sync twins
         self._arrays = arrays if arrays is not None else device_graph(graph)
         self._labeled = plan.vlabels is not None
         if self._labeled and _labs_of(self._arrays) is None:
@@ -675,34 +727,21 @@ class Matcher:
             ))
         return self._fns[capacity]
 
-    def _level_cb(self, i, thunk):
-        """`--trace-sync` per-level hook: one `executor.level` span per
-        schedule position, fenced with block_until_ready so the span
-        duration is real device time, annotated with the surviving
-        frontier size and the level's capacity demand."""
-        with get_tracer().span("executor.level", level=i) as sp:
-            out = thunk()
-            jax.block_until_ready(out)
-            if isinstance(out, tuple) and len(out) == 3:
-                _, new_valid, needed = out
-                sp.set(needed=int(needed))
-                if new_valid is not None:
-                    sp.set(frontier=int(new_valid.sum()))
-        return out
+    def _slots(self, capacity: int) -> list:
+        return fill_slots(self.plan, self._W,
+                          replace(self.cfg, capacity=capacity))
 
-    def _traced_fn(self, capacity: int):
-        """Eager (un-jitted) twin of :meth:`_fn` with the per-level span
-        hook — dispatched only when the tracer asks for device-fenced
-        levels (`--trace-sync`): per-level spans are impossible inside
-        one jitted program, and the fencing serializes the pipeline, so
-        this path must never be the default."""
-        if capacity not in self._traced_fns:
-            self._traced_fns[capacity] = _make_count_fn(
-                self.plan, self._W, _bs_iters(self._W),
-                replace(self.cfg, capacity=capacity),
-                level_cb=self._level_cb,
-            )
-        return self._traced_fns[capacity]
+    def _fill_attrs(self, capacity: int, fill) -> dict:
+        """Window fill of one dispatch, for its `executor.dispatch` span:
+        per launched window [scope, rows, valid entries, slots streamed
+        (cap x width)] under `windows`, and their totals."""
+        windows = [[scope, rows, valid, cap * width]
+                   for (scope, cap, width), (rows, valid)
+                   in zip(self._slots(capacity), fill.tolist()) if rows]
+        return {"rows_live": sum(w[1] for w in windows),
+                "entries_valid": sum(w[2] for w in windows),
+                "entries_streamed": sum(w[3] for w in windows),
+                "windows": windows}
 
     def warmup(self, *, chunk: int | None = None) -> None:
         """Compile against a sentinel frontier.  Pass the same `chunk`
@@ -732,9 +771,10 @@ class Matcher:
     def install_exported(self, data: bytes, *,
                          chunk: int | None = None) -> None:
         """Install a serialized AOT program as the base-capacity count
-        fn.  Raises ValueError when the blob targets another platform or
-        was traced against different array shapes — callers catch it and
-        fall back to a fresh :meth:`warmup` JIT."""
+        fn.  Raises ValueError when the blob targets another platform,
+        was traced against different array shapes, or lacks the window
+        fill output — callers catch it and fall back to a fresh
+        :meth:`warmup` JIT."""
         exported = jax.export.deserialize(data)
         backend = jax.default_backend()
         if backend not in exported.platforms:
@@ -750,6 +790,11 @@ class Matcher:
         got = tuple(tuple(a.shape) for a in exported.in_avals)
         if got != want:
             raise ValueError(f"AOT input shapes {got} != expected {want}")
+        outs = tuple(tuple(a.shape) for a in exported.out_avals)
+        want = ((), (), (len(self._slots(self.cfg.capacity)), 2))
+        if outs != want:
+            raise ValueError(f"AOT output shapes {outs} != expected {want} "
+                             f"(count, needed, window fill)")
         self._fns[self.cfg.capacity] = jax.jit(exported.call)
 
     def release(self) -> None:
@@ -758,7 +803,6 @@ class Matcher:
         (the resident graph shared via ``arrays=`` stays alive at its
         owner).  The matcher is unusable afterwards."""
         self._fns.clear()
-        self._traced_fns.clear()
         self._arrays = None
 
     def rebind(self, arrays, *, graph=None) -> None:
@@ -818,9 +862,6 @@ class Matcher:
         graph, cfg = self.graph, self.cfg
         call_args = self._call_args()
         tr = get_tracer()
-        # per-level device fencing is strictly opt-in (tracer.sync =
-        # --trace-sync): the eager twin serializes the dispatch pipeline
-        trace_sync = tr.enabled and tr.sync
         if state is None:
             chunk = min(chunk or cfg.capacity, cfg.capacity)
             # spans: (start, end, capacity).  Start at the last count's
@@ -837,7 +878,7 @@ class Matcher:
                                                          1)
         with jax.enable_x64(True), tr.span(
                 "executor.count", depth=self.plan.depth,
-                buckets=cfg.fingerprint(), sync=trace_sync,
+                buckets=cfg.fingerprint(),
                 resumed=state.dispatches > 0) as csp:
             spans = state.spans
             segment = 0
@@ -845,36 +886,45 @@ class Matcher:
                 s, e, cap = spans.pop()
                 self._capacity = max(self._capacity, cap)
                 width = min(chunk, cap)
+                # the dispatch's host turn, split in its children: the
+                # roots, the launch, and the blocking readbacks
                 with tr.span("executor.dispatch", v0_start=s, v0_end=e,
                              capacity=cap, frontier=e - s) as dsp:
-                    v0 = jnp.arange(s, e, dtype=jnp.int32)
-                    if e - s < width:
-                        v0 = jnp.pad(v0, (0, width - (e - s)),
-                                     constant_values=graph.n)
+                    with tr.span("executor.roots"):
+                        v0 = jnp.arange(s, e, dtype=jnp.int32)
+                        if e - s < width:
+                            v0 = jnp.pad(v0, (0, width - (e - s)),
+                                         constant_values=graph.n)
                     # fn resolution inside the span: a cold capacity
                     # (escalation) compiles here, attributed to this
                     # dispatch
-                    fn = (self._traced_fn(cap) if trace_sync
-                          else self._fn(cap))
-                    cnt, needed = fn(*call_args, v0)
-                    # int() blocks until the device result is ready, so
-                    # the dispatch span always covers real compute time
-                    needed = int(needed)
+                    with tr.span("executor.launch"):
+                        cnt, needed, fill = self._fn(cap)(*call_args, v0)
+                    with tr.span("executor.readback"):
+                        # the window fill crosses to the host only while
+                        # tracing, in the transfer that brings `needed`
+                        if tr.enabled:
+                            needed, fill = jax.device_get((needed, fill))
+                        # int() blocks until the device result is ready,
+                        # so the dispatch span covers real compute time
+                        needed = int(needed)
                     dsp.set(needed=needed)
-                segment += 1
-                state.dispatches += 1
-                state.max_needed = max(state.max_needed, needed)
-                if needed > cap:
-                    if e - s > 1:
-                        mid = (s + e) // 2
-                        spans += [(s, mid, cap), (mid, e, cap)]
-                    elif cap < self.MAX_CAPACITY:
-                        spans.append((s, e, cap * 2))   # escalate
-                    else:
+                    if tr.enabled:
+                        dsp.set(**self._fill_attrs(cap, fill))
+                    segment += 1
+                    state.dispatches += 1
+                    state.max_needed = max(state.max_needed, needed)
+                    if needed > cap:
+                        if e - s > 1:
+                            mid = (s + e) // 2
+                            spans += [(s, mid, cap), (mid, e, cap)]
+                            continue
+                        if cap < self.MAX_CAPACITY:
+                            spans.append((s, e, cap * 2))   # escalate
+                            continue
                         state.overflowed = True  # cannot split/grow further
+                    with tr.span("executor.readback"):
                         state.total += int(cnt)
-                    continue
-                state.total += int(cnt)
             csp.set(dispatches=segment, max_needed=state.max_needed,
                     preempted=bool(spans))
         if spans:
@@ -963,11 +1013,14 @@ class ShardedMatcher:
 
                 def body(carry, v0c):
                     tot, mx = carry
+                    # the window fill is dropped: summed over the scan it
+                    # loses which windows launched, so no cap x width
                     if labeled:
-                        cnt, needed = count_fn(indptr, degrees, flat,
-                                               labs, v0c)
+                        cnt, needed, _ = count_fn(indptr, degrees, flat,
+                                                  labs, v0c)
                     else:
-                        cnt, needed = count_fn(indptr, degrees, flat, v0c)
+                        cnt, needed, _ = count_fn(indptr, degrees, flat,
+                                                  v0c)
                     return (tot + cnt, jnp.maximum(mx, needed)), ()
 
                 init = (jnp.zeros((), jnp.int64), jnp.zeros((), jnp.int32))

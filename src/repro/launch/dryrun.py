@@ -157,7 +157,7 @@ def lower_graphpi(mesh, mesh_name: str, *, buckets: bool | None = None):
     ax = tuple(axes) if len(axes) > 1 else axes[0]
 
     def shard_fn(indptr, degrees, flat, v0_local):
-        cnt, needed = count_fn(indptr, degrees, flat, v0_local)
+        cnt, needed, _ = count_fn(indptr, degrees, flat, v0_local)
         return jax.lax.psum(cnt, ax), jax.lax.pmax(needed, ax)
 
 

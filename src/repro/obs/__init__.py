@@ -24,6 +24,8 @@ from repro.obs.trace import (
     Timer,
     Tracer,
     get_tracer,
+    now_ns,
+    set_annotator,
     set_tracer,
     timer,
 )
@@ -38,6 +40,8 @@ __all__ = [
     "Tracer",
     "get_tracer",
     "latency_summary",
+    "now_ns",
+    "set_annotator",
     "set_tracer",
     "timer",
 ]

@@ -1,15 +1,12 @@
 """Shared `--trace` wiring for the serving/mining CLIs.
 
 Every front door (`launch/gateway.py`, `launch/query_serve.py`,
-`launch/mine.py`) takes the same three flags:
+`launch/mine.py`) takes the same two flags:
 
     --trace OUT.json     enable the tracer, export a Perfetto-loadable
                          trace on exit (inspect with
                          `python -m repro.obs summarize OUT.json` or
                          https://ui.perfetto.dev)
-    --trace-sync         also fence per-level executor spans with
-                         block_until_ready (real device time per level;
-                         serializes the dispatch pipeline — opt-in)
     --metrics OUT.json   dump a MetricsRegistry snapshot on exit
 
 `add_trace_args` registers them; `start_tracing` installs the tracer
@@ -30,10 +27,6 @@ def add_trace_args(ap) -> None:
     g.add_argument("--trace", default="", metavar="OUT.json",
                    help="export a Chrome/Perfetto trace of this run "
                         "(summarize: python -m repro.obs summarize)")
-    g.add_argument("--trace-sync", action="store_true",
-                   help="with --trace: fence per-level executor spans "
-                        "(block_until_ready) so span durations are real "
-                        "device time — serializes the hot path")
     g.add_argument("--metrics", default="", metavar="OUT.json",
                    help="dump the metrics-registry snapshot as JSON")
 
@@ -43,7 +36,7 @@ def start_tracing(args) -> Tracer:
     the env-configured tracer (REPRO_TRACE) stays as-is, so the flags
     only ever widen observability."""
     if args.trace:
-        return set_tracer(Tracer(enabled=True, sync=args.trace_sync))
+        return set_tracer(Tracer(enabled=True))
     return get_tracer()
 
 

@@ -24,7 +24,17 @@ Design constraints, in order:
     thread gets its own parent chain; spans never parent across
     threads) and finished spans append to one lock-guarded list.
   * JAX-FREE.  serve/scheduler.py imports this module and is linted to
-    never touch JAX; everything here is stdlib.
+    never touch JAX; everything here is stdlib.  The JAX side puts spans
+    on the profiler's clock through a hook it installs
+    (`set_annotator`, core/executor.py): while a tracer is enabled each
+    span also opens the hook's annotation, so a profiler capture shows
+    program spans beside the device ops.  A disabled tracer never calls
+    it.
+
+Spans that do not nest on one thread's stack (an RPC request handled by
+interleaved coroutines) are opened with `Tracer.begin(...)` and closed
+with `.end(...)`, or recorded after the fact with `Tracer.record(...)`
+from two `now_ns()` readings.
 
 The exporter writes the Chrome trace-event format (`ph: "X"` complete
 events with microsecond timestamps) wrapped as {"traceEvents": [...]},
@@ -46,6 +56,8 @@ __all__ = [
     "Timer",
     "Tracer",
     "get_tracer",
+    "now_ns",
+    "set_annotator",
     "set_tracer",
     "timer",
 ]
@@ -80,6 +92,33 @@ def timer() -> Timer:
     return Timer()
 
 
+def now_ns() -> int:
+    """The tracer's clock (`perf_counter_ns`), for the start and end of a
+    span recorded with `Tracer.record`; the sanctioned raw reading where
+    `no-raw-timing` applies."""
+    return time.perf_counter_ns()
+
+
+# the profiler hook: name -> an annotation context (entered at span open,
+# exited at span close) or None; installed by the JAX side
+_annotator = None
+
+
+def set_annotator(fn) -> None:
+    """Install `fn(name)` as the hook every span of an enabled tracer
+    calls at open: it returns a context manager that marks the span in
+    a profiler capture, or None when no capture is recording."""
+    global _annotator
+    _annotator = fn
+
+
+def _annotate(name: str):
+    ann = _annotator(name) if _annotator is not None else None
+    if ann is not None:
+        ann.__enter__()
+    return ann
+
+
 class _NopSpan:
     """Shared do-nothing span: the entire disabled-tracer cost."""
 
@@ -94,6 +133,9 @@ class _NopSpan:
     def set(self, **attrs) -> "_NopSpan":
         return self
 
+    def end(self, **attrs) -> None:
+        return None
+
 
 _NOP = _NopSpan()
 
@@ -103,7 +145,7 @@ class Span:
     attributes discovered mid-span (they export under `args`)."""
 
     __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id",
-                 "tid", "t0_ns", "dur_ns")
+                 "tid", "t0_ns", "dur_ns", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -114,6 +156,7 @@ class Span:
         self.tid = 0
         self.t0_ns = 0
         self.dur_ns = 0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -130,11 +173,14 @@ class Span:
         self.parent_id = stack[-1].span_id if stack else None
         self.tid = threading.get_ident()
         stack.append(self)
+        self._ann = _annotate(self.name)
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = self._tracer._stack()
         # tolerate exotic exits (a span leaked past its parent's exit):
         # unwind to self so one bad caller can't corrupt the whole stack
@@ -146,23 +192,43 @@ class Span:
         return False
 
 
+class Pending:
+    """A span that does not nest (`Tracer.begin`): opened in one
+    coroutine and ended in another, recorded as a root span of the
+    thread that ends it."""
+
+    __slots__ = ("_tracer", "name", "attrs", "t0_ns", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self._tracer = tracer
+        self.name = name
+        self.attrs = attrs
+        self._ann = _annotate(name)
+        self.t0_ns = time.perf_counter_ns()
+
+    def set(self, **attrs) -> "Pending":
+        self.attrs.update(attrs)
+        return self
+
+    def end(self, **attrs) -> None:
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self._tracer.record(self.name, self.t0_ns, t1,
+                            **{**self.attrs, **attrs})
+
+
 class Tracer:
     """Span collector.  `enabled=False` (the default process tracer) is
     the production mode: every `span()` call returns the shared no-op.
-
-    `sync` requests device-fenced per-level executor spans (the
-    `--trace-sync` flag): the executor inserts `block_until_ready`
-    fences so span durations are real device time — strictly opt-in
-    because fencing serializes the dispatch pipeline.
 
     `max_spans` bounds memory on long serving runs; once full, new
     spans are counted in `dropped` instead of recorded.
     """
 
-    def __init__(self, *, enabled: bool = True, sync: bool = False,
-                 max_spans: int = 1_000_000):
+    def __init__(self, *, enabled: bool = True, max_spans: int = 1_000_000):
         self.enabled = enabled
-        self.sync = sync
         self.max_spans = max_spans
         self.dropped = 0
         self.epoch_ns = time.perf_counter_ns()
@@ -185,8 +251,30 @@ class Tracer:
             return _NOP
         return Span(self, name, attrs)
 
+    def begin(self, name: str, **attrs):
+        """Open a span that does not nest on this thread's stack; close
+        it with `.end(**more_attrs)`, from any coroutine or thread."""
+        if not self.enabled:
+            return _NOP
+        return Pending(self, name, attrs)
+
+    def record(self, name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+        """Record a finished span timed elsewhere, from two `now_ns()`
+        readings.  It has no parent; the calling thread is its `tid`."""
+        if not self.enabled:
+            return
+        self._append({
+            "name": name,
+            "id": next(self._ids),
+            "parent": None,
+            "tid": threading.get_ident(),
+            "t0_ns": t0_ns - self.epoch_ns,
+            "dur_ns": t1_ns - t0_ns,
+            "attrs": attrs,
+        })
+
     def _finish(self, span: Span) -> None:
-        rec = {
+        self._append({
             "name": span.name,
             "id": span.span_id,
             "parent": span.parent_id,
@@ -194,7 +282,9 @@ class Tracer:
             "t0_ns": span.t0_ns - self.epoch_ns,
             "dur_ns": span.dur_ns,
             "attrs": span.attrs,
-        }
+        })
+
+    def _append(self, rec: dict) -> None:
         with self._lock:
             if len(self._spans) >= self.max_spans:
                 self.dropped += 1
@@ -257,10 +347,8 @@ class Tracer:
 
 def _from_env() -> Tracer:
     # REPRO_TRACE=1 pre-enables tracing before any code runs (the
-    # benchmark harness path); REPRO_TRACE_SYNC=1 adds device fencing.
-    on = os.environ.get("REPRO_TRACE", "") not in ("", "0")
-    sync = os.environ.get("REPRO_TRACE_SYNC", "") not in ("", "0")
-    return Tracer(enabled=on, sync=sync)
+    # benchmark harness path)
+    return Tracer(enabled=os.environ.get("REPRO_TRACE", "") not in ("", "0"))
 
 
 _tracer = _from_env()

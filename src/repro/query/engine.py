@@ -610,6 +610,7 @@ class QueryEngine:
                 "engine.execute", pattern=lead.pattern.name or "anon",
                 canon_key=entry.canon_key, cache_hit=hit,
                 riders=len(fl.tickets) - 1,
+                tickets=[t.seq for t in fl.tickets],
                 resumed=fl.state is not None):
             with timer() as t_run:
                 if self._maintainer is not None:

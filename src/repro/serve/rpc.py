@@ -43,6 +43,11 @@ workloads' quanta (and the engine's preemption budget), so the loop
 returns to the sockets promptly even mid-huge-query.  Result waiters
 park on an event that pulses once per round.
 
+TRACING.  Each submit opens an `rpc.request` span when its frame is
+handled and ends it when the result reply is written, with the ticket
+(the engine's `seq`) and the tenant; `engine.execute` lists the tickets
+it serves, so a request's spans join on its ticket.
+
 The counts are BIT-IDENTICAL to the in-process path: the server calls
 the same `QueryEngine.run_pending` rounds a local Gateway would
 (`scripts/gateway_smoke.sh` replays one trace through both and diffs
@@ -66,6 +71,7 @@ import struct
 import sys
 from dataclasses import asdict
 
+from ..obs import get_tracer
 from ..query.engine import Rejection
 
 __all__ = [
@@ -156,6 +162,7 @@ class GatewayRPCServer:
         self.port = port             # 0 = ephemeral; real port set on serve
         self._get_pattern = get_pattern
         self._tickets: dict[int, object] = {}
+        self._requests: dict[int, object] = {}   # seq -> open rpc.request
         self._work: asyncio.Event | None = None
         self._round_ev: asyncio.Event | None = None
         self._stop_ev: asyncio.Event | None = None
@@ -236,6 +243,10 @@ class GatewayRPCServer:
                     resp = {"ok": False, "error": f"{type(e).__name__}: {e}"}
                 writer.write(encode_frame(resp))
                 await writer.drain()
+                if msg.get("op") == "result":
+                    request = self._requests.pop(msg.get("ticket"), None)
+                    if request is not None:
+                        request.end(ok=bool(resp.get("ok")))
         finally:
             writer.close()
 
@@ -271,11 +282,19 @@ class GatewayRPCServer:
         return {"ok": False, "error": f"unknown op {op!r}"}
 
     def _submit(self, msg: dict) -> dict:
-        req = request_from_spec(msg, self._get_pattern)
-        out = self.engine.try_enqueue(req)
+        request = get_tracer().begin("rpc.request")
+        try:
+            req = request_from_spec(msg, self._get_pattern)
+            out = self.engine.try_enqueue(req)
+        except Exception:
+            request.end(ok=False)
+            raise
         if isinstance(out, Rejection):
+            request.end(tenant=req.tenant, ok=False)
             return {"ok": False, "error": "rejected",
                     "rejection": asdict(out)}
+        request.set(ticket=out.seq, tenant=req.tenant)
+        self._requests[out.seq] = request
         self.workload.tickets.append(out)
         self._tickets[out.seq] = out
         self._work.set()

@@ -28,7 +28,7 @@ from repro.core.schedule import generate_schedules
 from repro.graph.datasets import erdos_renyi, rmat
 from repro.kernels import ops, ref
 
-# house/cycle5 are the slowest executor-level parity cases → tagged slow;
+# house/cycle5 are the slowest whole-executor parity cases → tagged slow;
 # the remaining patterns keep fused-vs-portable coverage in the default run
 PATTERNS = [pytest.param(p, id=p.name,
                          marks=pytest.mark.slow

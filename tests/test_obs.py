@@ -13,7 +13,7 @@ from repro.core.executor import ExecutorConfig, compute_stats
 from repro.graph.datasets import erdos_renyi
 from repro.obs import (
     Histogram, MetricsRegistry, Tracer, get_tracer, latency_summary,
-    set_tracer, timer,
+    now_ns, set_tracer, timer,
 )
 from repro.obs.metrics import _key, percentile
 from repro.obs.summarize import main as summarize_main, summarize
@@ -99,6 +99,119 @@ def test_disabled_tracer_is_shared_noop_and_cheap():
         best = min(best, t.seconds)
     # ~0.4us/span measured; generous 2us bound for loaded CI machines
     assert best / n < 2e-6, f"{best / n * 1e9:.0f}ns per disabled span"
+
+
+def test_record_and_begin_are_thread_and_coroutine_safe():
+    """Spans recorded from many threads at once are all kept, each with
+    its own id; spans begun and ended by interleaved coroutines overlap
+    without nesting."""
+    import asyncio
+    import sys
+
+    tr = Tracer(enabled=True)
+    n_threads, per = 8, 500
+
+    def worker(k):
+        for i in range(per):
+            t0 = now_ns()
+            tr.record("t.rec", t0, now_ns(), worker=k, i=i)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    recs = tr.spans()
+    assert len(recs) == n_threads * per
+    assert len({r["id"] for r in recs}) == len(recs)
+    assert {(r["attrs"]["worker"], r["attrs"]["i"]) for r in recs} == {
+        (k, i) for k in range(n_threads) for i in range(per)}
+    assert all(r["parent"] is None and r["dur_ns"] >= 0 for r in recs)
+
+    async def request(k):
+        pending = tr.begin("c.req", k=k)
+        await asyncio.sleep(0)
+        pending.end(done=True)
+
+    async def serve():
+        await asyncio.gather(*(request(k) for k in range(50)))
+
+    tr.clear()
+    asyncio.run(serve())
+    reqs = tr.spans()
+    assert sorted(r["attrs"]["k"] for r in reqs) == list(range(50))
+    assert all(r["attrs"]["done"] and r["parent"] is None for r in reqs)
+    # every request began before any ended: they overlap on one thread
+    assert max(r["t0_ns"] for r in reqs) < min(
+        r["t0_ns"] + r["dur_ns"] for r in reqs)
+
+
+def test_annotator_runs_only_while_the_tracer_is_enabled(monkeypatch):
+    import repro.obs.trace as trace_mod
+
+    events = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_mod, "_annotator", Annotation)
+    off = Tracer(enabled=False)
+    with off.span("a.b"):
+        pass
+    off.begin("a.c").end()
+    off.record("a.d", 0, 1)
+    assert events == [] and len(off) == 0
+    on = Tracer(enabled=True)
+    with on.span("x.outer"):
+        with on.span("x.inner"):
+            pass
+    on.begin("x.req").end(ok=True)
+    assert events == [("enter", "x.outer"), ("enter", "x.inner"),
+                      ("exit", "x.inner"), ("exit", "x.outer"),
+                      ("enter", "x.req"), ("exit", "x.req")]
+    # with no capture recording the hook returns None: spans still record
+    monkeypatch.setattr(trace_mod, "_annotator", lambda name: None)
+    with on.span("y.z"):
+        pass
+    assert [s["name"] for s in on.spans()] == [
+        "x.inner", "x.outer", "x.req", "y.z"]
+
+
+def test_spans_are_host_events_of_a_profiler_capture(tracer, tmp_path):
+    """The executor's hook puts every span of an enabled tracer into a
+    running profiler capture, on the capture's own clock."""
+    import glob
+
+    import jax
+
+    import repro.core.executor  # noqa: F401 — installs the hook
+
+    with tracer.span("probe.before"):
+        pass
+    with jax.profiler.trace(str(tmp_path)):
+        with tracer.span("probe.outer"):
+            tracer.begin("probe.detached").end()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"probe.outer", "probe.detached"} <= names
+    assert "probe.before" not in names
 
 
 def test_chrome_export_round_trips(tracer, tmp_path):
@@ -321,6 +434,21 @@ def test_gateway_replay_span_nesting(tracer, obs_graph, obs_stats):
             assert parent_name(s) == "engine.round"
         elif s["name"] == "executor.dispatch":
             assert parent_name(s) == "executor.count"
+        elif s["name"] in ("executor.roots", "executor.launch",
+                           "executor.readback"):
+            assert parent_name(s) == "executor.dispatch"
+    # each dispatch's host turn: its roots, one launch, and one readback
+    # of `needed` (a second of the count where the chunk did not overflow)
+    dispatches = [s for s in spans if s["name"] == "executor.dispatch"]
+    assert dispatches
+    for d in dispatches:
+        kids = [s["name"] for s in spans if s["parent"] == d["id"]]
+        assert kids[:3] == ["executor.roots", "executor.launch",
+                            "executor.readback"]
+        assert kids[3:] == (["executor.readback"]
+                            if d["attrs"]["needed"] <= d["attrs"]["capacity"]
+                            else [])
+        assert d["attrs"]["entries_valid"] <= d["attrs"]["entries_streamed"]
     # coalescing evidence rides on the round + execute spans: each
     # iso duplicate becomes a rider on its class lead, never a second
     # execution
@@ -330,6 +458,9 @@ def test_gateway_replay_span_nesting(tracer, obs_graph, obs_stats):
     execs = [s for s in spans if s["name"] == "engine.execute"]
     assert len(execs) == 2
     assert sum(s["attrs"]["riders"] for s in execs) == 2
+    # a request's spans join on its ticket: every ticket is listed once
+    assert sorted(t for s in execs for t in s["attrs"]["tickets"]) == [
+        0, 1, 2, 3]
     # the trace localizes time: leaf spans cover >=95% of the wall
     doc = {"traceEvents": tracer.chrome_events()}
     assert summarize(doc)["leaf_coverage"] >= 0.95

@@ -142,6 +142,42 @@ def test_socket_counts_bit_identical(graph, stats):
     assert engine.preemptions >= 1            # budget was actually active
 
 
+def test_socket_request_spans_join_on_their_ticket(graph, stats):
+    """Each submit's ticket is on its `rpc.request` span and on the
+    `engine.execute` spans that served it, which the request span
+    covers."""
+    from repro.obs import Tracer, get_tracer, set_tracer
+
+    old = get_tracer()
+    tracer = set_tracer(Tracer(enabled=True))
+    engine = QueryEngine(graph, cfg=CFG, stats=stats)
+    server, th, port = _start_server(engine)
+    client = RPCClient("127.0.0.1", port, tenant="acme", timeout=120.0)
+    try:
+        tickets = [client.submit(spec) for spec in TRACE]
+        for tk in tickets:
+            client.result(tk)
+    finally:
+        client.shutdown()
+        client.close()
+        th.join(timeout=30)
+        set_tracer(old)
+    assert not th.is_alive()
+    spans = tracer.spans()
+    reqs = {s["attrs"]["ticket"]: s for s in spans
+            if s["name"] == "rpc.request"}
+    assert sorted(reqs) == sorted(tickets)
+    for tk, req in reqs.items():
+        assert req["attrs"]["tenant"] == "acme" and req["attrs"]["ok"]
+        assert req["parent"] is None
+        execs = [s for s in spans if s["name"] == "engine.execute"
+                 and tk in s["attrs"]["tickets"]]
+        assert execs
+        for s in execs:
+            assert req["t0_ns"] <= s["t0_ns"]
+            assert s["t0_ns"] + s["dur_ns"] <= req["t0_ns"] + req["dur_ns"]
+
+
 def test_socket_admission_rejection(graph, stats):
     """tenant_depth=0 rejects every submit: the wire carries the full
     Rejection payload and the client surfaces it as RPCError."""

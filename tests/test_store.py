@@ -202,6 +202,45 @@ def test_fresh_engine_replays_with_zero_searches_and_zero_compiles(
     assert all(r.search_seconds == 0.0 for r in results)
 
 
+def test_executable_without_window_fill_falls_back_to_jit(
+        warm_store, tiny_graph, tiny_stats, monkeypatch):
+    """A stored executable of a count program from before its window
+    fill output (two outputs) is refused on load, and the replica
+    re-JITs and counts the same."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.executor import Matcher
+
+    root, cold_counts = warm_store
+    load = PlanStore.load
+
+    def two_output_record(self, key):
+        rec = load(self, key)
+        if rec is None:
+            return None
+        m = Matcher(tiny_graph, rec.plan, CFG)
+        fn = m._fn(CFG.capacity)
+        v0 = jnp.full((CFG.capacity,), tiny_graph.n, jnp.int32)
+        with jax.enable_x64(True):
+            blob = jax.export.export(jax.jit(lambda *a: fn(*a)[:2]))(
+                *m._call_args(), v0).serialize()
+        return dataclasses.replace(rec, exec_bytes=blob)
+
+    monkeypatch.setattr(PlanStore, "load", two_output_record)
+    engine = QueryEngine(tiny_graph, cfg=CFG, store=PlanStore(root),
+                         stats=tiny_stats)
+    with pytest.warns(RuntimeWarning, match="window fill"):
+        results = engine.serve(workload())
+    stats = engine.cache.stats
+    assert [r.count for r in results] == cold_counts
+    assert stats.aot_load_fails == len(workload()) and stats.aot_loads == 0
+    assert stats.n_searches == 0
+    assert stats.n_compiles == len(workload())
+
+
 def test_warm_from_disk_preloads_then_serves_pure_hits(
         warm_store, tiny_graph, tiny_stats):
     root, cold_counts = warm_store
