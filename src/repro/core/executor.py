@@ -214,6 +214,68 @@ def fill_slots(plan: MatchingPlan, W: int,
             for _, w, cap, _, _ in ranges]
 
 
+def need_parts(graph, plan: MatchingPlan, cfg: ExecutorConfig,
+               W: int) -> list[tuple[int | None, np.ndarray]]:
+    """What each root adds to the `needed` a count program reads back,
+    from the host CSR: a list of (bucket index, rows), where rows[v] is
+    root v's share of one bucket window's rows, or of level 1's frontier
+    when the index is None.  A span's part is the sum over its roots; a
+    bucket part enters `needed` scaled by C / cap, as `scaled_need` does.
+
+    Two window steps are predicted, with the comparisons the program
+    makes: the step that consumes the roots (level 1, or each IEP union
+    when depth == 1), level 1's frontier when it expands, and the step
+    that consumes that frontier (level 2, or each IEP union when
+    depth == 2).  The maximum of the parts is a lower bound on the
+    device's `needed`, exact for plans no deeper than that.  Labeled
+    plans get no parts."""
+    if plan.vlabels is not None:
+        return []
+    n = graph.n
+    deg = np.asarray(graph.degrees, dtype=np.int64)
+    layout = list(_bucket_ranges(_bucket_layout(cfg, W), cfg.capacity))
+    parts = []
+
+    def steps(i):
+        """Predecessor columns of each window step at loop position i."""
+        if i < plan.depth:
+            return [plan.preds[i]]
+        return list(plan.iep.unions) if plan.iep is not None else []
+
+    def bucket_rows(emb, preds, owner):
+        # the base `pick_base` picks: the first lowest degree, or the last
+        pv = np.stack([emb[p] for p in preds])
+        if cfg.dynamic_base and len(preds) > 1:
+            sel = np.argmin(deg[pv], axis=0)
+            base = np.take_along_axis(pv, sel[None, :], axis=0)[0]
+        else:
+            base = pv[-1]
+        db = deg[base]
+        for bi, width, _, lo, is_last in layout:
+            rows = (db > lo) & (is_last | (db <= width))
+            parts.append((bi, np.bincount(owner[rows], minlength=n)))
+
+    roots = np.arange(n, dtype=np.int64)
+    for preds in steps(1):
+        bucket_rows((roots,), preds, roots)
+    if plan.depth < 2 or (plan.iep is None and plan.n == 2):
+        return parts
+    src = np.repeat(roots, deg)
+    within = np.arange(len(src)) - np.repeat(np.cumsum(deg) - deg, deg)
+    dst = np.asarray(graph.indices, dtype=np.int64)[
+        np.asarray(graph.indptr, dtype=np.int64)[src] + within]
+    keep = np.ones(len(src), dtype=bool)
+    # level 1's comparisons are all against column 0, the root
+    for _, d in tuple(plan.restr[1]) + tuple((j, 0) for j in plan.neqs[1]):
+        keep &= ((dst > src) if d > 0 else (dst < src) if d < 0
+                 else (dst != src))
+    src, dst = src[keep], dst[keep]
+    parts.append((None, np.bincount(src, minlength=n)))
+    for preds in steps(2):
+        bucket_rows((src, dst), preds, src)
+    return parts
+
+
 @dataclass
 class CountResult:
     count: int
@@ -710,6 +772,8 @@ class Matcher:
                 f"labeled pattern {plan.pattern.name!r} cannot run against "
                 f"unlabeled graph {graph.name!r}")
         self._capacity = self.cfg.capacity    # sticky escalated capacity
+        self._need = None                     # prefix sums of need_parts
+        self._planned: dict[tuple, tuple] = {}  # (capacity, chunk) -> spans
 
     def _call_args(self):
         """Positional args ahead of v0 — labeled plans append the label
@@ -742,6 +806,54 @@ class Matcher:
                 "entries_valid": sum(w[2] for w in windows),
                 "entries_streamed": sum(w[3] for w in windows),
                 "windows": windows}
+
+    def _need_sums(self) -> list:
+        """need_parts prefix-summed over root ids, once per matcher."""
+        if self._need is None:
+            self._need = [(b, np.concatenate([[0], np.cumsum(rows)]))
+                          for b, rows in need_parts(self.graph, self.plan,
+                                                    self.cfg, self._W)]
+        return self._need
+
+    def _limits(self, capacity: int) -> list:
+        """(prefix sums, most a span may hold) of each need part at
+        `capacity`: a bucket's rows fit its cap, the frontier fits C."""
+        caps = [cap for _, _, cap, _, _ in _bucket_ranges(
+            _bucket_layout(self.cfg, self._W), capacity)]
+        return [(pre, capacity if b is None else caps[b])
+                for b, pre in self._need_sums()]
+
+    def predicted(self, start: int, end: int, capacity: int,
+                  width: int) -> int:
+        """The host's prediction of the `needed` a dispatch of roots
+        [start, end) at `capacity` and v0 width `width` reads back: a
+        lower bound, exact for the plans `need_parts` covers."""
+        need = width
+        for pre, lim in self._limits(capacity):
+            t = int(pre[end] - pre[start])
+            need = max(need, -(-t * capacity // lim))
+        return need
+
+    def planned_spans(self, capacity: int, chunk: int) -> list:
+        """The span stack a fresh count starts from: greedy cuts of the
+        root id range, each the longest of at most `chunk` roots whose
+        predicted need fits `capacity` (a root over it alone), ascending
+        so the stack pops the last first.  Where fixed `chunk`-wide
+        spans would not overflow, these are those spans."""
+        key = (capacity, chunk)
+        if key not in self._planned:
+            n = self.graph.n
+            limits = self._limits(capacity)
+            spans, s = [], 0
+            while s < n:
+                e = min(s + chunk, n)
+                for pre, lim in limits:
+                    e = min(e, int(np.searchsorted(
+                        pre, pre[s] + lim, side="right")) - 1)
+                spans.append((s, max(e, s + 1), capacity))
+                s = spans[-1][1]
+            self._planned[key] = tuple(spans)
+        return list(self._planned[key])
 
     def warmup(self, *, chunk: int | None = None) -> None:
         """Compile against a sentinel frontier.  Pass the same `chunk`
@@ -804,6 +916,8 @@ class Matcher:
         owner).  The matcher is unusable afterwards."""
         self._fns.clear()
         self._arrays = None
+        self._need = None
+        self._planned.clear()
 
     def rebind(self, arrays, *, graph=None) -> None:
         """Swap the resident device arrays for same-shaped replacements
@@ -837,6 +951,8 @@ class Matcher:
                     f"rebind vertex count {graph.n} != {self.graph.n}")
             self.graph = graph
         self._arrays = arrays
+        self._need = None
+        self._planned.clear()
 
     def count(self, *, chunk: int | None = None) -> CountResult:
         """Chunked outer loop; a chunk that overflows capacity is bisected
@@ -862,17 +978,18 @@ class Matcher:
         graph, cfg = self.graph, self.cfg
         call_args = self._call_args()
         tr = get_tracer()
+        planned = None
         if state is None:
             chunk = min(chunk or cfg.capacity, cfg.capacity)
-            # spans: (start, end, capacity).  Start at the last count's
+            # spans: (start, end, capacity), cut from the host's
+            # prediction of each span's need.  Start at the last count's
             # escalated capacity so warm repeats (the serve path) skip
             # the doomed undersized passes.
-            cap0 = self._capacity
             state = CountState(
-                spans=[(s, min(s + chunk, graph.n), cap0)
-                       for s in range(0, graph.n, chunk)],
+                spans=self.planned_spans(self._capacity, chunk),
                 chunk=chunk,
             )
+            planned = len(state.spans)
         chunk = state.chunk
         budget = None if max_dispatches is None else max(int(max_dispatches),
                                                          1)
@@ -910,7 +1027,8 @@ class Matcher:
                         needed = int(needed)
                     dsp.set(needed=needed)
                     if tr.enabled:
-                        dsp.set(**self._fill_attrs(cap, fill))
+                        dsp.set(predicted=self.predicted(s, e, cap, width),
+                                **self._fill_attrs(cap, fill))
                     segment += 1
                     state.dispatches += 1
                     state.max_needed = max(state.max_needed, needed)
@@ -927,6 +1045,8 @@ class Matcher:
                         state.total += int(cnt)
             csp.set(dispatches=segment, max_needed=state.max_needed,
                     preempted=bool(spans))
+            if planned is not None:
+                csp.set(planned_spans=planned)
         if spans:
             return state, None
         return state, CountResult(count=state.total // self.plan.iep_divisor,

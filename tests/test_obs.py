@@ -466,6 +466,32 @@ def test_gateway_replay_span_nesting(tracer, obs_graph, obs_stats):
     assert summarize(doc)["leaf_coverage"] >= 0.95
 
 
+def test_dispatch_spans_carry_the_planned_need(tracer, obs_graph,
+                                               obs_stats):
+    """Span planning rides on the trace: a count that starts afresh
+    carries `planned_spans` on its `executor.count`, and every
+    `executor.dispatch` the host's `predicted` need, a lower bound on
+    the device's `needed` wherever it fits capacity."""
+    _replay(obs_graph, obs_stats, MetricsRegistry())
+    spans = tracer.spans()
+    counts = [s["attrs"] for s in spans if s["name"] == "executor.count"]
+    fresh = [c for c in counts if "planned_spans" in c]
+    assert len(fresh) == 2                 # one per executed class
+    assert all(not c["resumed"] and c["planned_spans"] >= 1 for c in fresh)
+    assert all(c["resumed"] for c in counts if "planned_spans" not in c)
+    dispatches = [s["attrs"] for s in spans
+                  if s["name"] == "executor.dispatch"]
+    assert dispatches
+    for d in dispatches:
+        if d["predicted"] <= d["capacity"]:
+            assert d["predicted"] <= d["needed"]
+        else:
+            assert d["needed"] > d["capacity"]
+    # nothing overflowed, so every dispatch ran a planned span
+    assert all(d["needed"] <= d["capacity"] for d in dispatches)
+    assert sum(c["planned_spans"] for c in fresh) == len(dispatches)
+
+
 def test_registry_snapshot_stable_across_replays(obs_graph, obs_stats):
     """Two identical replays on fresh engines expose the same snapshot
     key set with the same integer counters (latency values differ)."""
