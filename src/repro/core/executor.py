@@ -214,66 +214,105 @@ def fill_slots(plan: MatchingPlan, W: int,
             for _, w, cap, _, _ in ranges]
 
 
-def need_parts(graph, plan: MatchingPlan, cfg: ExecutorConfig,
-               W: int) -> list[tuple[int | None, np.ndarray]]:
-    """What each root adds to the `needed` a count program reads back,
-    from the host CSR: a list of (bucket index, rows), where rows[v] is
-    root v's share of one bucket window's rows, or of level 1's frontier
-    when the index is None.  A span's part is the sum over its roots; a
-    bucket part enters `needed` scaled by C / cap, as `scaled_need` does.
+def window_steps(plan: MatchingPlan) -> int:
+    """Window steps of a count program: each expansion level, and the
+    IEP tail (all its unions consume one frontier) when there is one."""
+    return plan.depth - 1 + (plan.iep is not None)
 
-    Two window steps are predicted, with the comparisons the program
-    makes: the step that consumes the roots (level 1, or each IEP union
-    when depth == 1), level 1's frontier when it expands, and the step
-    that consumes that frontier (level 2, or each IEP union when
-    depth == 2).  The maximum of the parts is a lower bound on the
-    device's `needed`, exact for plans no deeper than that.  Labeled
-    plans get no parts."""
+
+def need_parts(graph, plan: MatchingPlan, cfg: ExecutorConfig, W: int,
+               ) -> tuple[int, list[tuple[int | None, np.ndarray]]]:
+    """What each root adds to the `needed` a count program reads back,
+    from the host CSR: (steps, parts), where each part is (bucket index,
+    rows) and rows[v] is root v's share of one bucket window's rows, or
+    of a level's frontier when the index is None.  A span's part is the
+    sum over its roots; a bucket part enters `needed` scaled by C / cap,
+    as `scaled_need` does.
+
+    The host enumerates the frontier level by level with the program's
+    membership tests and comparisons, from the roots on, and counts each
+    window step's rows per bucket (base picked as `pick_base` picks it)
+    and each frontier it expands.  It expands a level only while the
+    candidate entries that costs (the rows' base degrees) fit the slots
+    one dispatch streams at a window step, so the host holds no more
+    than the program's own windows; `steps` is how many of the plan's
+    `window_steps` it counted.
+
+    The maximum of the parts is a lower bound on the device's `needed`,
+    exact where every step is counted.  Labeled plans get no parts."""
     if plan.vlabels is not None:
-        return []
+        return 0, []
     n = graph.n
     deg = np.asarray(graph.degrees, dtype=np.int64)
+    indptr = np.asarray(graph.indptr, dtype=np.int64)
+    indices = np.asarray(graph.indices, dtype=np.int64)
     layout = list(_bucket_ranges(_bucket_layout(cfg, W), cfg.capacity))
-    parts = []
+    budget = sum(cap * width for _, width, cap, _, _ in layout)
+    # the last level keeps a frontier for the IEP tail, else it counts
+    last_frontier = plan.depth - (1 if plan.iep is not None else 2)
+    parts, edge_keys = [], None
 
-    def steps(i):
-        """Predecessor columns of each window step at loop position i."""
+    def windows(i):
+        """Predecessor columns of each window at loop position i."""
         if i < plan.depth:
             return [plan.preds[i]]
         return list(plan.iep.unions) if plan.iep is not None else []
 
-    def bucket_rows(emb, preds, owner):
-        # the base `pick_base` picks: the first lowest degree, or the last
+    def pick_base(emb, preds):
+        # as the program's `pick_base`: the first lowest degree, or the last
         pv = np.stack([emb[p] for p in preds])
         if cfg.dynamic_base and len(preds) > 1:
             sel = np.argmin(deg[pv], axis=0)
-            base = np.take_along_axis(pv, sel[None, :], axis=0)[0]
-        else:
-            base = pv[-1]
-        db = deg[base]
-        for bi, width, _, lo, is_last in layout:
-            rows = (db > lo) & (is_last | (db <= width))
-            parts.append((bi, np.bincount(owner[rows], minlength=n)))
+            return np.take_along_axis(pv, sel[None, :], axis=0)[0]
+        return pv[-1]
 
-    roots = np.arange(n, dtype=np.int64)
-    for preds in steps(1):
-        bucket_rows((roots,), preds, roots)
-    if plan.depth < 2 or (plan.iep is None and plan.n == 2):
-        return parts
-    src = np.repeat(roots, deg)
-    within = np.arange(len(src)) - np.repeat(np.cumsum(deg) - deg, deg)
-    dst = np.asarray(graph.indices, dtype=np.int64)[
-        np.asarray(graph.indptr, dtype=np.int64)[src] + within]
-    keep = np.ones(len(src), dtype=bool)
-    # level 1's comparisons are all against column 0, the root
-    for _, d in tuple(plan.restr[1]) + tuple((j, 0) for j in plan.neqs[1]):
-        keep &= ((dst > src) if d > 0 else (dst < src) if d < 0
-                 else (dst != src))
-    src, dst = src[keep], dst[keep]
-    parts.append((None, np.bincount(src, minlength=n)))
-    for preds in steps(2):
-        bucket_rows((src, dst), preds, src)
-    return parts
+    def window(base):
+        """(row, neighbour) of every entry of each base's CSR row."""
+        db = deg[base]
+        row = np.repeat(np.arange(len(base)), db)
+        return row, indices[np.repeat(indptr[base], db) + np.arange(len(row))
+                            - np.repeat(np.cumsum(db) - db, db)]
+
+    def is_edge(u, v):
+        nonlocal edge_keys
+        if edge_keys is None:
+            src, dst = window(np.arange(n))
+            edge_keys = np.sort(src * n + dst)
+        at = np.minimum(np.searchsorted(edge_keys, u * n + v),
+                        len(edge_keys) - 1)
+        return edge_keys[at] == u * n + v
+
+    def expand(emb, i, base):
+        """Level i's frontier: each row's neighbours of its base that pass
+        level i's comparisons and are adjacent to every predecessor."""
+        row, cand = window(base)
+        keep = np.ones(len(row), dtype=bool)
+        for col, d in tuple(plan.restr[i]) + tuple(
+                (j, 0) for j in plan.neqs[i]):
+            ev = emb[col][row]
+            keep &= (cand > ev) if d > 0 else (cand < ev) if d < 0 \
+                else (cand != ev)
+        row, cand = row[keep], cand[keep]
+        # a lone predecessor is the base itself: its members all pass
+        for p in plan.preds[i] if len(plan.preds[i]) > 1 else ():
+            keep = is_edge(emb[p][row], cand)
+            row, cand = row[keep], cand[keep]
+        return [c[row] for c in emb] + [cand]
+
+    emb, steps = [np.arange(n, dtype=np.int64)], 0
+    for i in range(1, window_steps(plan) + 1):
+        for preds in windows(i):
+            base = pick_base(emb, preds)
+            db = deg[base]
+            for bi, width, _, lo, is_last in layout:
+                rows = (db > lo) & (is_last | (db <= width))
+                parts.append((bi, np.bincount(emb[0][rows], minlength=n)))
+        steps = i
+        if i > last_frontier or db.sum() > budget:
+            break
+        emb = expand(emb, i, base)
+        parts.append((None, np.bincount(emb[0], minlength=n)))
+    return steps, parts
 
 
 @dataclass
@@ -773,6 +812,7 @@ class Matcher:
                 f"unlabeled graph {graph.name!r}")
         self._capacity = self.cfg.capacity    # sticky escalated capacity
         self._need = None                     # prefix sums of need_parts
+        self._steps = 0                       # window steps they cover
         self._planned: dict[tuple, tuple] = {}  # (capacity, chunk) -> spans
 
     def _call_args(self):
@@ -810,10 +850,16 @@ class Matcher:
     def _need_sums(self) -> list:
         """need_parts prefix-summed over root ids, once per matcher."""
         if self._need is None:
+            self._steps, parts = need_parts(self.graph, self.plan,
+                                            self.cfg, self._W)
             self._need = [(b, np.concatenate([[0], np.cumsum(rows)]))
-                          for b, rows in need_parts(self.graph, self.plan,
-                                                    self.cfg, self._W)]
+                          for b, rows in parts]
         return self._need
+
+    def predicted_steps(self) -> int:
+        """How many of the plan's `window_steps` the host plan covers."""
+        self._need_sums()
+        return self._steps
 
     def _limits(self, capacity: int) -> list:
         """(prefix sums, most a span may hold) of each need part at
@@ -1046,7 +1092,9 @@ class Matcher:
             csp.set(dispatches=segment, max_needed=state.max_needed,
                     preempted=bool(spans))
             if planned is not None:
-                csp.set(planned_spans=planned)
+                csp.set(planned_spans=planned,
+                        predicted_steps=self.predicted_steps(),
+                        window_steps=window_steps(self.plan))
         if spans:
             return state, None
         return state, CountResult(count=state.total // self.plan.iep_divisor,

@@ -31,6 +31,46 @@ class LabelView(NamedTuple):
     max_label_degree: np.ndarray  # [L] int32, max over v of lens[v, c]
 
 
+def _mix(x: np.ndarray) -> np.ndarray:
+    """splitmix64's finaliser, element-wise over uint64 (wrapping)."""
+    x = (x ^ (x >> np.uint64(33))) * np.uint64(0xFF51AFD7ED558CCD)
+    x = (x ^ (x >> np.uint64(33))) * np.uint64(0xC4CEB9FE1A85EC53)
+    return x ^ (x >> np.uint64(33))
+
+
+def refined_colours(n: int, lo: np.ndarray, hi: np.ndarray,
+                    labels: np.ndarray | None = None) -> np.ndarray:
+    """[n] int64 colour classes of colour refinement (1-WL) over the
+    undirected simple edges (lo, hi), started from (degree, label).  Each
+    round hashes a vertex's class with the multiset of its neighbours'
+    classes, until a round splits no class.  Class ids are ranks of those
+    hashes, never of vertex ids, so a relabelled copy of the graph gets
+    the same colour at each image vertex."""
+    src = np.concatenate([lo, hi])
+    dst = np.concatenate([hi, lo])
+    order = np.argsort(src, kind="stable")
+    src, dst = src[order], dst[order]
+    deg = np.bincount(src, minlength=n)
+    starts = np.cumsum(deg) - deg
+    has = deg > 0
+    col = deg.astype(np.uint64)
+    if labels is not None:
+        col = col ^ _mix(labels.astype(np.uint64) + np.uint64(1))
+    key = np.unique(col, return_inverse=True)[1].reshape(-1)
+    with np.errstate(over="ignore"):
+        while True:
+            nbr = _mix(key.astype(np.uint64) + np.uint64(1))[dst]
+            total = np.zeros(n, dtype=np.uint64)
+            if len(nbr):
+                total[has] = np.add.reduceat(nbr, starts[has])
+            col = _mix(key.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+                       ^ total)
+            new = np.unique(col, return_inverse=True)[1].reshape(-1)
+            if new.max(initial=-1) == key.max(initial=-1):
+                return new
+            key = new
+
+
 @dataclass(frozen=True)
 class GraphCSR:
     n: int                     # vertices
@@ -75,8 +115,13 @@ class GraphCSR:
             )
             # densest-first relabel: new id 0 = highest degree.  With the
             # executor's strided task striping this balances per-device work
-            # and makes `id(a) > id(b)` restrictions prune early.
-            perm = np.argsort(-deg, kind="stable")
+            # and makes `id(a) > id(b)` restrictions prune early.  Equal
+            # degrees are ordered by their refined colour, so the layout,
+            # and the span plan cut along it, do not depend on the ids the
+            # edges came with; only vertices refinement cannot tell apart
+            # keep their input order.
+            colour = refined_colours(n, lo, hi, labels)
+            perm = np.lexsort((colour, -deg))
             inv = np.empty(n, dtype=np.int64)
             inv[perm] = np.arange(n)
             lo, hi = inv[lo], inv[hi]

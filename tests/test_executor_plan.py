@@ -12,6 +12,7 @@ from repro.configs.graphpi import get_pattern
 from repro.core.config_search import search_configuration
 from repro.core.executor import (
     CountState, ExecutorConfig, Matcher, auto_buckets, device_graph,
+    window_steps,
 )
 from repro.core.oracle import count_embeddings_oracle
 from repro.core.pattern import clique, cycle, star
@@ -48,10 +49,11 @@ def _searched(pattern, graph, use_iep=False):
 
 
 def _case(name, graph):
-    """(pattern, plan, config) of the triangle, or of star4 with its
-    leaves folded by IEP (depth 1, one root a row)."""
-    if name == "triangle":
-        return (*_searched(clique(3), graph), CFG)
+    """(pattern, plan, config) of the triangle, the 4-clique, or star4
+    with its leaves folded by IEP (depth 1, one root a row)."""
+    if name in ("triangle", "clique4"):
+        return (*_searched(clique(4 if name == "clique4" else 3), graph),
+                CFG)
     canon, plan = _searched(star(4), graph)
     k = best_iep_k(canon, plan.order, plan.res_set)
     plan = build_plan(canon, plan.order, plan.res_set, iep_k=k)
@@ -78,11 +80,13 @@ def _traced_count(matcher, **kw):
             [s["attrs"] for s in spans if s["name"] == "executor.count"])
 
 
-@pytest.mark.parametrize("name", ["triangle", "star4-iep"])
+@pytest.mark.parametrize("name", ["triangle", "star4-iep", "clique4"])
 def test_planned_dispatches_need_what_was_predicted(graph, name):
-    """Triangle and IEP star on a power-law graph with three buckets:
-    every planned dispatch reads back the `needed` the host predicted,
-    none overflows, and fixed spans of the same width would have."""
+    """Triangle, IEP star and 4-clique (whose level-2 frontier and
+    level-3 windows the host enumerates) on a power-law graph with three
+    buckets: every planned dispatch reads back the `needed` the host
+    predicted, none overflows, and fixed spans of the same width would
+    have."""
     canon, plan, cfg = _case(name, graph)
     chunk = 64
     m = Matcher(graph, plan, cfg)
@@ -102,9 +106,10 @@ def test_planned_dispatches_need_what_was_predicted(graph, name):
 @pytest.mark.parametrize("pattern", [cycle(4), clique(4)],
                          ids=["cycle4", "clique4"])
 def test_deeper_plans_need_at_least_the_prediction(graph, pattern):
-    """Past level 2 the device's `needed` is only bounded below by the
-    prediction: a dispatch predicted within capacity needs at least
-    that, one predicted over it overflows."""
+    """Past the levels the host expands (the cycle's level-2 candidates
+    outnumber the slots of one dispatch) the device's `needed` is only
+    bounded below by the prediction: a dispatch predicted within
+    capacity needs at least that, one predicted over it overflows."""
     canon, plan = _searched(pattern, graph)
     assert plan.depth == 4
     out, disp, _ = _traced_count(Matcher(graph, plan, CFG), chunk=32)
@@ -123,15 +128,15 @@ def small():
 
 
 @pytest.mark.parametrize("chunk", [8, 32])
-@pytest.mark.parametrize("name", ["triangle", "star4-iep",
+@pytest.mark.parametrize("name", ["triangle", "star4-iep", "clique4",
                                   *(f"P{i}" for i in range(1, 7))])
 def test_planned_counts_equal_fixed_spans_and_oracle(graph, small, name,
                                                      chunk):
     """Planned and fixed spans give bit-identical raw totals, and the
-    count equals the oracle's: the triangle and the IEP star on the
-    bucketed power-law graph (where the plans differ at the wider
-    chunk), P1-P6 on a small one."""
-    if name in ("triangle", "star4-iep"):
+    count equals the oracle's: the triangle, the IEP star and the
+    4-clique on the bucketed power-law graph (where the plans differ at
+    the wider chunk), P1-P6 on a small one."""
+    if name in ("triangle", "star4-iep", "clique4"):
         g, chunk = graph, chunk * 4
         canon, plan, cfg = _case(name, g)
     else:
@@ -205,11 +210,9 @@ def test_rebind_keeps_counts_exact(graph):
             assert m.planned_spans(CFG.capacity, 64) != before
 
 
-def test_graph500_s13_triangle_plans_seven_spans():
-    """The premise of planning in the graph500-s13 cell, on the host: the
-    searched triangle plan over the cell's graph, capacity and buckets
-    cuts 7 spans, none predicted over capacity (fixed spans take 19
-    dispatches there, 9 of them overflowing)."""
+def _s13(seed):
+    """The graph500-s13 cell's graph (labels of `seed`) and the engine's
+    capacity and bucket layout, as the served stack builds them."""
     import json
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -221,16 +224,84 @@ def test_graph500_s13_triangle_plans_seven_spans():
 
     with open(os.path.join(bench, "configs", "graph500-s13.json")) as f:
         config = json.load(f)
-    n, edges = make_edges(config["generator"], 7100000011)
+    n, edges = make_edges(config["generator"], seed)
     g = server.build_graph(n, edges, config["name"])
     cap = int(config["engine"]["capacity"])
-    cfg = ExecutorConfig(capacity=cap, degree_buckets=auto_buckets(g))
+    return g, cap, ExecutorConfig(capacity=cap, degree_buckets=auto_buckets(g))
+
+
+@pytest.fixture(scope="module")
+def s13():
+    return _s13(7100000011)
+
+
+def test_graph500_s13_triangle_plans_seven_spans(s13):
+    """The premise of planning in the graph500-s13 cell, on the host: the
+    searched triangle plan over the cell's graph, capacity and buckets
+    cuts 7 spans, none predicted over capacity (fixed spans take 19
+    dispatches there, 9 of them overflowing)."""
+    g, cap, cfg = s13
     _, plan = _searched(clique(3), g)
     assert plan.preds == ((), (0,), (0, 1))
     m = Matcher(g, plan, cfg)
     spans = m.planned_spans(cap, cap)
     assert [s[:2] for s in spans] == [
-        (0, 132), (132, 220), (220, 294), (294, 362), (362, 1033),
-        (1033, 2842), (2842, 8192)]
+        (0, 132), (132, 220), (220, 294), (294, 361), (361, 1031),
+        (1031, 2831), (2831, 8192)]
     assert all(m.predicted(s, e, c, cap) <= c for s, e, c in spans)
     assert m.predicted(0, g.n, cap, cap) > cap
+
+
+def test_graph500_s13_clique4_plans_every_level(s13):
+    """The premise of the graph500-s13 4-clique cell, on the host: the
+    host plan of the searched 4-clique plan covers all three window
+    steps, its level-2 frontier is the graph's 1,181,673 triangles, and
+    it cuts 150 spans, none predicted over capacity (the triangle's
+    stays 7).  Levels 1-2 alone cut 7 spans, which the device bisects."""
+    g, cap, cfg = s13
+    _, plan = _searched(clique(4), g)
+    assert plan.preds == ((), (0,), (0, 1), (0, 1, 2))
+    m = Matcher(g, plan, cfg)
+    assert m.predicted_steps() == window_steps(plan) == 3
+    frontiers = [int(pre[-1]) for b, pre in m._need_sums() if b is None]
+    assert frontiers == [g.m, 1181673]
+    spans = m.planned_spans(cap, cap)
+    assert len(spans) == 150
+    assert all(m.predicted(s, e, c, cap) <= c for s, e, c in spans)
+    tri = Matcher(g, _searched(clique(3), g)[1], cfg)
+    assert len(tri.planned_spans(cap, cap)) == 7
+
+
+@pytest.mark.parametrize("seed", [3000000010, 3000000011])
+def test_graph500_s13_cut_does_not_move_with_the_seed(seed):
+    """The cell's labels come from `--seed`; the degree relabel orders
+    equal degrees by refined colour, so the 4-clique still cuts 150 spans
+    and the triangle 7 (with ties left in input order, these seeds cut
+    149 and 151)."""
+    g, cap, cfg = _s13(seed)
+    for k, want in ((4, 150), (3, 7)):
+        m = Matcher(g, _searched(clique(k), g)[1], cfg)
+        assert len(m.planned_spans(cap, cap)) == want
+
+
+def test_degree_relabel_ignores_the_input_ids():
+    """Refined colours follow the vertices through any relabelling of
+    the input; where they tell every vertex apart (er64), the relabelled
+    CSR is the same array whatever ids the edges came with."""
+    from repro.graph.csr import refined_colours
+
+    for g in (rmat(7, 6, seed=5, name="rmat7"),
+              erdos_renyi(64, 200, seed=1, name="er64")):
+        e = g.edge_array()
+        base = refined_colours(g.n, e[:, 0], e[:, 1])
+        layouts = set()
+        for s in range(4):
+            p = np.random.default_rng(s).permutation(g.n)
+            pe = np.sort(p[e], axis=1)
+            assert np.array_equal(
+                refined_colours(g.n, pe[:, 0], pe[:, 1])[p], base)
+            h = GraphCSR.from_edges(g.n, pe, relabel_by_degree=True)
+            assert h.m == g.m
+            layouts.add(h.fingerprint)
+        if len(np.unique(base)) == g.n:
+            assert len(layouts) == 1

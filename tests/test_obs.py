@@ -492,6 +492,25 @@ def test_dispatch_spans_carry_the_planned_need(tracer, obs_graph,
     assert sum(c["planned_spans"] for c in fresh) == len(dispatches)
 
 
+def test_count_spans_carry_the_predicted_steps(tracer, obs_graph,
+                                               obs_stats):
+    """A fresh `executor.count` says how many of its plan's window steps
+    the host plan covers (`predicted_steps`) beside the plan's total
+    (`window_steps`).  On er64 every level's candidates fit one
+    dispatch's slots, so the host enumerates the whole plan, the
+    triangle's two steps and the house's four, and every dispatch reads
+    back exactly the need the host predicted."""
+    _replay(obs_graph, obs_stats, MetricsRegistry())
+    spans = tracer.spans()
+    fresh = [s["attrs"] for s in spans if s["name"] == "executor.count"
+             and "planned_spans" in s["attrs"]]
+    assert sorted((c["predicted_steps"], c["window_steps"])
+                  for c in fresh) == [(2, 2), (4, 4)]
+    dispatches = [s["attrs"] for s in spans
+                  if s["name"] == "executor.dispatch"]
+    assert all(d["needed"] == d["predicted"] for d in dispatches)
+
+
 def test_registry_snapshot_stable_across_replays(obs_graph, obs_stats):
     """Two identical replays on fresh engines expose the same snapshot
     key set with the same integer counters (latency values differ)."""
