@@ -86,6 +86,52 @@ def _segment_member(flat, lo, hi, target, iters: int):
     return found
 
 
+def _row_slices(flat, start, width: int):
+    """[rows, width] windows flat[start[r] : start[r] + width], one
+    contiguous slice per row rather than `width` scalar indices.  A
+    slice that would run past the array reads as -1 (never a vertex)
+    instead of being clamped, so no window is silently shifted."""
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,))
+    return jax.lax.gather(flat, start[:, None], dn, slice_sizes=(width,),
+                          mode=jax.lax.GatherScatterMode.FILL_OR_DROP,
+                          fill_value=-1)
+
+
+def candidate_window(flat, indptr, degrees, base, width: int, *,
+                     labs=None, label=None, pallas: str = "0"):
+    """(cand, ok) [rows, width]: the candidate window at each row's
+    `base`, the `width` entries of the flat CSR array from the base
+    row's offset, and which of them lie inside the row.  Each row is
+    gathered as one contiguous slice: by the Pallas row gather (`pallas`
+    "1" compiled for the TPU, "interpret" in the Pallas interpreter), or
+    by XLA slices ("0").  With a `label`
+    requirement the window comes from the base row's per-label segment
+    (lab_flat is grouped by destination label, each segment sorted by
+    id), so the label mask is applied by construction BEFORE any
+    membership test — the rows that reach the intersection kernels are
+    already label-pruned.
+
+    Every slice lies inside its array: each offset, the sentinel row
+    n's included, leaves at least max-degree ≥ width entries after it.
+    The CSR build pads `indices`, and so `lab_flat`, with max-degree
+    sentinels past the last row (`GraphCSR.from_edges`); a live view
+    keeps a window-wide tail past its patch region (`live/overlay.py`)."""
+    if label is not None:
+        _, lab_starts, lab_lens, src = labs
+        start, length = lab_starts[base, label], lab_lens[base, label]
+    else:
+        src, start, length = flat, indptr[base], degrees[base]
+    if pallas == "0":
+        cand = _row_slices(src, start, width)
+    else:
+        from ..kernels.ops import window_gather
+        cand = window_gather(src, start, width=width,
+                             interpret=pallas == "interpret")
+    ok = jnp.arange(width)[None, :] < length[:, None]
+    return cand, ok
+
+
 def _bs_iters(max_degree: int) -> int:
     return max(1, math.ceil(math.log2(max(max_degree, 2))) + 1)
 
@@ -370,26 +416,12 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
 
     buckets = _bucket_layout(cfg, W)
 
-    def gather_window(flat, indptr, degrees, base, width, *, labs=None,
-                      label=None):
-        """Candidate window at `base`.  With a `label` requirement the
-        window comes from the base row's per-label segment (lab_flat is
-        grouped by destination label, each segment sorted by id), so the
-        label mask is applied by construction BEFORE any membership test
-        — the rows that reach the intersection kernels are already
-        label-pruned."""
-        if label is not None:
-            _, lab_starts, lab_lens, lab_flat = labs
-            start = lab_starts[base, label]
-            cand = lab_flat[start[:, None]
-                            + jnp.arange(width, dtype=start.dtype)[None, :]]
-            ok = jnp.arange(width)[None, :] < lab_lens[base, label][:, None]
-            return cand, ok
-        start = indptr[base]
-        cand = flat[start[:, None]
-                    + jnp.arange(width, dtype=start.dtype)[None, :]]
-        ok = jnp.arange(width)[None, :] < degrees[base][:, None]
-        return cand, ok
+    # the window gather's kernel runs where Mosaic compiles it; the
+    # interpreter takes a host callback per row DMA, so the fused path's
+    # CPU parity runs gather their windows as XLA slices (the kernel's
+    # own parity is tests/test_window_gather.py)
+    gather_window = partial(
+        candidate_window, pallas="1" if cfg.pallas_mode() == "1" else "0")
 
     def base_degrees(degrees, pv, *, labs=None, label=None):
         """Candidate-set size per predecessor: the full degree, or the
@@ -552,10 +584,13 @@ def _make_count_fn(plan: MatchingPlan, W: int, iters: int,
                     bucket_total = (pos[-1] + 1).astype(jnp.int32)
                     out_idx = jnp.where(flat_mask,
                                         jnp.minimum(offset + pos, C), C)
-                    rows_local = (jnp.arange(cap * width, dtype=jnp.int32)
-                                  // width)
+                    # each window entry's frontier row, broadcast along
+                    # the row (a gather by entry would cost as much as
+                    # a scalar window gather)
                     parent = parent.at[out_idx].set(
-                        jnp.take(sel_idx, rows_local), mode="drop")
+                        jnp.broadcast_to(sel_idx[:, None],
+                                         (cap, width)).reshape(-1),
+                        mode="drop")
                     newcol = newcol.at[out_idx].set(cand.reshape(-1),
                                                     mode="drop")
                     return parent, newcol, offset + bucket_total
@@ -741,24 +776,24 @@ def device_graph(graph: GraphCSR) -> DeviceGraph:
     by every cached matcher instead of re-uploading per pattern.
 
     The flat indices array is padded ONCE here with never-matching
-    sentinels to a whole number of gather tiles, so the fused kernel's
-    aligned tile DMAs (DESIGN.md §4) read a free [F/128, 128] view of
-    it and no kernel call re-pads the resident graph.
+    sentinels to a whole number of gather tiles, so the kernels' aligned
+    tile DMAs (DESIGN.md §4) — the fused level kernel's predecessor
+    rows and the candidate-window gather — read a free [F/128, 128]
+    view of it and no kernel call re-pads the resident graph.
 
     Labeled graphs additionally upload the per-label CSR view: vlabels
     padded to [n+1] with -1 (the frontier's sentinel root n never
     matches a label), lab_starts/lab_lens padded with an all-empty row n
-    for the same reason.  lab_flat only ever feeds host-side gathers —
-    never the kernel's DMAs — so it needs no extra sentinel pad beyond
-    the max-degree pad the CSR build already applies."""
+    for the same reason.  lab_flat feeds the candidate-window gather, so
+    it is padded to whole tiles as well."""
     from ..kernels.ops import flat_gather_pad
 
+    def tiled(a):
+        return jnp.asarray(np.concatenate([
+            a, np.full(flat_gather_pad(len(a)), np.iinfo(np.int32).max,
+                       dtype=np.int32)]))
+
     degrees = np.concatenate([graph.degrees, np.zeros(1, dtype=np.int32)])
-    flat = np.concatenate([
-        graph.indices,
-        np.full(flat_gather_pad(len(graph.indices)),
-                np.iinfo(np.int32).max, dtype=np.int32),
-    ])
     labs = None
     if graph.labels is not None:
         lv = graph.label_view
@@ -773,12 +808,12 @@ def device_graph(graph: GraphCSR) -> DeviceGraph:
             jnp.asarray(vlabels),
             jnp.asarray(lab_starts),
             jnp.asarray(lab_lens),
-            jnp.asarray(lv.flat),
+            tiled(lv.flat),
         )
     return DeviceGraph(
         jnp.asarray(graph.indptr),
         jnp.asarray(degrees),
-        jnp.asarray(flat),
+        tiled(graph.indices),
         labs,
     )
 

@@ -14,6 +14,7 @@ from .intersect import (
     CAND_PAD, NBR_PAD, intersect_count_pallas, level_expand_pallas,
     membership_pallas,
 )
+from .window import BLOCK_ROWS, window_gather_pallas
 
 # Tile width of the kernel's neighbor gather: the flat CSR array is read
 # as rows of GATHER_TILE int32s (one lane-row of a vreg), and every DMA
@@ -183,6 +184,40 @@ def level_expand(
         block_b=block_b, interpret=interpret,
     )
     return out[:B] if count else out[:B, :D]
+
+
+@partial(jax.jit, static_argnames=("width", "interpret"))
+def window_gather(
+    flat: jax.Array,                      # [F] flat CSR indices array
+    starts: jax.Array,                    # [B] window offsets
+    *,
+    width: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """win[b, :] = flat[starts[b] : starts[b] + width] as [B, width]
+    int32, each row read as the aligned GATHER_TILE-wide tiles that
+    cover it (one DMA per row) instead of `width` scalar indices.
+
+    Contract: starts[b] + width ≤ len(flat) for every row — the windows
+    of a sentinel-padded CSR array (`GraphCSR.from_edges` pads `indices`
+    with max-degree sentinels).  Nothing past a row's window is read, so
+    no window is clamped or shifted.  A `flat` whose length is not a
+    multiple of GATHER_TILE is padded here (device_graph pads the
+    resident arrays once)."""
+    (B,) = starts.shape
+    starts = starts.astype(jnp.int32)
+    pb = (-B) % BLOCK_ROWS
+    if pb:
+        # padded rows read the window at 0, inside the array as well
+        starts = jnp.pad(starts, (0, pb))
+    flat = flat.astype(jnp.int32)
+    pad = flat_gather_pad(flat.shape[0])
+    if pad:
+        flat = jnp.concatenate([flat, jnp.full(pad, NBR_PAD, jnp.int32)])
+    out = window_gather_pallas(
+        flat.reshape(-1, GATHER_TILE), starts, width=width,
+        interpret=interpret)
+    return out.reshape(B + pb, -1)[:B, :width]
 
 
 # ------------------------------------------------------------ attention ---

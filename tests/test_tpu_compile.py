@@ -16,6 +16,7 @@ layout the launchers use.
 import dataclasses
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -122,32 +123,94 @@ def test_level_expand_compiles_for_v5e(mode, mico, one_chip,
         assert "tpu_custom_call" in compiled.as_text(), s.label
 
 
-def test_count_program_compiles_and_fits_one_chip(mico, one_chip,
-                                                  no_compile_cache,
-                                                  monkeypatch):
+@pytest.mark.parametrize("bucket", [0, 1, 2])
+def test_window_gather_compiles_for_v5e(bucket, mico, one_chip,
+                                        no_compile_cache):
+    """The candidate-window row gather at each degree bucket's (cap,
+    width), under x64 as the executor traces it, compiles to a Mosaic
+    custom call with a [cap, width] result."""
+    g, cfg = mico
+    width, frac = cfg.degree_buckets[bucket]
+    width = min(width, g.max_degree)
+    cap = max(int(cfg.capacity * frac), 8)
+    flat_len = len(g.indices) + ops.flat_gather_pad(len(g.indices))
+    with jax.enable_x64(True):
+        compiled = jax.jit(lambda f, s: ops.window_gather(
+            f, s, width=width)).lower(_spec((flat_len,), one_chip),
+                                      _spec((cap,), one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "window_gather" in text
+    assert compiled.out_info.shape == (cap, width)
+
+
+@pytest.fixture(scope="module")
+def clique4_program(mico, one_chip):
     """One whole single-device count program (4-clique: levels with two
     and three predecessors, a restricted mask level and a counting last
     level) for mico-syn at the serving capacity, traced under x64 as
-    `Matcher` traces it, holds the Mosaic kernel and fits in 16 GB."""
+    `Matcher` traces it and compiled for the described chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     g, cfg = mico
-    # the executor picks the compiled kernel only on a TPU backend; this
-    # process's backend is the CPU, so steer that one query here
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert cfg.pallas_mode() == "1"
-    pattern = clique(4)
-    rs = generate_restriction_sets(pattern, max_sets=1)[0]
-    plan = build_plan(pattern, tuple(range(4)), rs)
-    W = g.max_degree
-    fn = _make_count_fn(plan, W, _bs_iters(W), cfg)
-    flat_len = len(g.indices) + ops.flat_gather_pad(len(g.indices))
-    args = (_spec((g.n + 1,), one_chip), _spec((g.n + 1,), one_chip),
-            _spec((flat_len,), one_chip),
-            _spec((cfg.capacity,), one_chip))
-    with jax.enable_x64(True):
-        compiled = jax.jit(fn).lower(*args).compile()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            # the executor picks the compiled kernels only on a TPU
+            # backend; this process's backend is the CPU, so steer that
+            # one query here
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            assert cfg.pallas_mode() == "1"
+            pattern = clique(4)
+            rs = generate_restriction_sets(pattern, max_sets=1)[0]
+            plan = build_plan(pattern, tuple(range(4)), rs)
+            W = g.max_degree
+            fn = _make_count_fn(plan, W, _bs_iters(W), cfg)
+            flat_len = len(g.indices) + ops.flat_gather_pad(len(g.indices))
+            args = (_spec((g.n + 1,), one_chip), _spec((g.n + 1,), one_chip),
+                    _spec((flat_len,), one_chip),
+                    _spec((cfg.capacity,), one_chip))
+            with jax.enable_x64(True):
+                compiled = jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return compiled, plan, flat_len
+
+
+def test_count_program_compiles_and_fits_one_chip(clique4_program):
+    """The 4-clique count program holds the Mosaic kernel and fits in
+    16 GB."""
+    compiled, _, flat_len = clique4_program
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, (total, mem)
     assert np.iinfo(np.int32).max > flat_len
+
+
+def test_count_program_gathers_each_window_once(clique4_program, mico):
+    """Each (level, bucket) window is one row gather in the compiled
+    program, and no gather there indexes the window's cap × width
+    entries one by one: neither the candidates nor the compaction's
+    parent rows."""
+    compiled, plan, _ = clique4_program
+    g, cfg = mico
+    text = compiled.as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "window_gather/pallas_call" in line]
+    scopes = sorted({re.search(r"level\d+/w\d+", c).group(0)
+                     for c in calls})
+    want = sorted(f"level{i}/w{min(w, g.max_degree)}"
+                  for i in range(1, plan.depth)
+                  for w, _ in cfg.degree_buckets)
+    assert scopes == want and len(calls) == len(want), calls
+    for w, f in cfg.degree_buckets:
+        w, cap = min(w, g.max_degree), max(int(cfg.capacity * f), 8)
+        for shape in (f"s32[{cap * w}]", f"s32[{cap},{w}]"):
+            assert not re.search(re.escape(shape) + r"\{[^}]*\} gather\(",
+                                 text), shape
